@@ -17,6 +17,7 @@ the same per-worker units the mesh reports natively.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Mapping, Optional
 
 import jax
@@ -54,15 +55,19 @@ def _sync_parts(eng, state):
     return parts
 
 
-def _expected_sync_ops(eng, state, backend: str = "sim") -> Optional[int]:
-    """Per-sync aggregation-op prediction, or None when no exact one exists.
+def _expected_sync_ops(eng, state, backend: str = "sim",
+                       level: int = 1) -> Optional[int]:
+    """Per-sync aggregation-op prediction for a level-``level`` sync, or None
+    when no exact one exists.
 
     Legacy roundtrip lowering: ``n_arrays × encode-keys`` — dtype buckets
     per part with fused comms on, leaves per part without.  When the sync
     lowers as a compressed collective (:func:`~repro.core.executors.
     _wire_eligible`), the codec owns the count instead:
     ``n_arrays × codec.lowered_sync_ops(backend)`` (int8 = quantized psum
-    [+ scale pmax under mesh], sign = vote + scale, ...).  Weighted
+    [+ scale pmax under mesh], sign = vote + scale, ...), times the number
+    of syncing groups under sim, which reduces each group's row slice on
+    its own (:class:`~repro.comms.reduce.SimWireOps`).  Weighted
     aggregators add a denominator reduction per array and ``exact=True``
     replays the whole sim reduce under one gather — neither has a clean
     closed form, so both defer to the budget."""
@@ -90,6 +95,8 @@ def _expected_sync_ops(eng, state, backend: str = "sim") -> Optional[int]:
                     # codecs (see Comms.sync): one reduce per LEAF
                     n_arrays = sum(len(jax.tree.leaves(p))
                                    for p in _sync_parts(eng, state))
+                if backend == "sim":
+                    n_arrays *= math.prod(topo.spec.group_sizes[:level - 1])
                 return n_arrays * per_array
     else:
         n_arrays = sum(len(jax.tree.leaves(p))
@@ -127,8 +134,8 @@ def audit_engine(eng, state, batch_fn: Optional[Callable[[int], Any]] = None,
     horizon = int(T) if T else topo.periods[0]
     schedule = topo.schedule(horizon)
 
-    expected_ops = _expected_sync_ops(eng, state,
-                                      "mesh" if is_mesh else "sim")
+    backend = "mesh" if is_mesh else "sim"
+    expected_ops = _expected_sync_ops(eng, state, backend)
     ws = eng.wire_stats(state)
     wire = None
     if ws is not None:
@@ -169,7 +176,10 @@ def audit_engine(eng, state, batch_fn: Optional[Callable[[int], Any]] = None,
             f32_elements //= n
         events[key] = EventAudit(
             key=key, level=ev.level, groups=ev.groups,
-            sync_ops=len(ops), expected_sync_ops=expected_ops,
+            sync_ops=len(ops),
+            expected_sync_ops=(None if expected_ops is None else
+                               _expected_sync_ops(eng, state, backend,
+                                                  ev.level)),
             ops=ops,
             axes=tuple(sorted({a for o in ops for a in o.axes})),
             wire_dtypes=tuple(sorted({d for o in ops for d in o.dtypes})),

@@ -10,7 +10,8 @@ primitive carries (``pjit``, ``scan``, ``shard_map``, ``cond`` branches,
 ``custom_jvp``/``vjp`` calls, ...) — and records the operations that matter
 for sync-plan auditing:
 
-* **collectives** — ``psum`` (and its ``check_rep`` rewrite ``psum2``),
+* **collectives** — ``psum`` (and its ``check_vma`` rewrite
+  ``psum_invariant``),
   ``pmean``\\*, ``all_gather``, ``all_to_all``, ``ppermute``, ... with their
   named axes, operand dtypes, element counts and bytes.  These ARE the wire
   under the mesh executor.  (\\*``lax.pmean`` lowers to psum + div, so it is
@@ -18,8 +19,9 @@ for sync-plan auditing:
   traffic, and is deliberately excluded.)
 * **reduces** — ``reduce_sum`` / ``dot_general``: the in-array reshape-mean
   and membership segment-mean forms the sim executor aggregates with.
-* **callbacks** / **transfers** — ``debug_callback``, ``pure_callback``,
-  ``io_callback``, ``device_put``, in/outfeed: host round-trips that must
+* **callbacks** / **transfers** — ``debug_callback``, ``debug_print``,
+  ``pure_callback``, ``io_callback``, ``device_put``, in/outfeed: host
+  round-trips that must
   never appear inside a compiled round body (rule R3).
 
 The result is plain data (:class:`JaxprSummary` of :class:`OpRecord`), so
@@ -41,14 +43,17 @@ try:  # jax >= 0.4.33: the public IR types
 except ImportError:  # pragma: no cover — older jax
     from jax.core import ClosedJaxpr, Jaxpr
 
-# psum2/pbroadcast are what check_rep=True shard_map rewrites psum into.
+# psum_invariant/pbroadcast are what check_vma=True shard_map rewrites psum
+# into (psum2 on older JAX).
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmean", "pmax", "pmin", "all_gather",
+    "psum", "psum2", "psum_invariant", "pmean", "pmax", "pmin", "all_gather",
     "all_gather_invariant", "all_to_all", "ppermute", "psum_scatter",
     "reduce_scatter",
 })
+# jax.debug.print binds its own debug_print primitive on JAX >= 0.9
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
 })
 TRANSFER_PRIMS = frozenset({"device_put", "infeed", "outfeed"})
 REDUCE_PRIMS = frozenset({"reduce_sum", "dot_general"})

@@ -98,9 +98,9 @@ class Compressor(abc.ABC):
 
     def lowered_sync_ops(self, backend: str) -> Optional[int]:
         """How many counted aggregation ops ONE :meth:`reduce` call lowers
-        to per payload buffer — in-array f32/i32 reduces under ``"sim"``,
-        named-axis collectives under ``"mesh"`` (the R1 prediction).  None
-        when no exact count exists."""
+        to per payload buffer — in-array f32/i32 reduces per syncing group
+        under ``"sim"``, named-axis collectives under ``"mesh"`` (the R1
+        prediction).  None when no exact count exists."""
         return None
 
     def __repr__(self):
@@ -167,16 +167,16 @@ class Int8Compressor(Compressor):
         127 * members < 2^31, and < 2^24 for any plausible group, so the
         f32 decode is exact too).  One decode at the end: qsum * scale /
         count."""
-        x2 = _rows(x).astype(jnp.float32)
+        x2 = _rows(x)  # read in its own dtype: the kernel widens per tile
         r, c = x2.shape
         nb = -(-c // self.block)
         pad = nb * self.block - c
         amax = jnp.pad(jnp.abs(x2), ((0, 0), (0, pad))) \
             .reshape(r, nb, self.block).max(axis=-1)          # (r, nb)
-        scale = ops.max(amax) / 127.0                          # group scale
+        scale = ops.max(amax.astype(jnp.float32)) / 127.0      # group scale
         q = _kernels.int8_scale_quantize(
             x2, scale, block=self.block, interpret=_interpret_default())
-        qsum = ops.sum(q.astype(jnp.int32))
+        qsum = ops.sum(q, jnp.int32)
         y = (jnp.pad(qsum.astype(jnp.float32), ((0, 0), (0, pad)))
              .reshape(r, nb, self.block) * scale[..., None]) \
             .reshape(r, nb * self.block)[:, :c]
@@ -185,8 +185,8 @@ class Int8Compressor(Compressor):
 
     def lowered_sync_ops(self, backend):
         # mesh: pmax on the scales + psum on the int32 payload; sim: the
-        # reshape-max of block stats is not a counted aggregation reduce,
-        # leaving only the int32 worker-axis sum
+        # max of block stats is not a counted aggregation reduce, leaving
+        # only the int32 worker-axis sum
         return 2 if backend == "mesh" else 1
 
     def wire_spec(self, length, dtype):
@@ -315,7 +315,7 @@ class TopKCompressor(Compressor):
         encode stay local and REPLICATE :meth:`roundtrip`'s casts exactly
         (so residual trajectories match the legacy path bitwise); the
         (values, indices) payload then rides ``ops.sparse_mean`` — a ragged
-        all-gather + fused Pallas decode-reduce on the mesh, the bitwise
+        all-gather + scatter-add decode-reduce on the mesh, the bitwise
         dense group mean under sim."""
         if residual is None:
             u = x
@@ -331,8 +331,8 @@ class TopKCompressor(Compressor):
         return out.astype(x.dtype).reshape(x.shape), new_res
 
     def lowered_sync_ops(self, backend):
-        # mesh: all_gather of values + all_gather of indices (the fused
-        # decode-reduce is kernel-internal); sim: one dense f32 group mean
+        # mesh: all_gather of values + all_gather of indices (the
+        # scatter-add decode-reduce is local); sim: one dense f32 group mean
         return 2 if backend == "mesh" else 1
 
     def wire_spec(self, length, dtype):

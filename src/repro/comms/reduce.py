@@ -10,9 +10,9 @@ is the ENCODED payload:
 * :meth:`mean` — the aggregator's f32 group mean (the identity codec's
   whole lowering; bitwise-identical to ``UniformTopology.aggregate`` with
   the default :class:`~repro.core.aggregators.MeanAggregator`);
-* :meth:`sum` — dtype-preserving group sum: an int8 payload widened to
-  int32 psums AS int32 (exact, order-independent — the int8 compressed
-  allreduce);
+* :meth:`sum` — group sum in the payload's dtype, or in ``dtype`` when
+  given: an int8 payload widened to int32 psums AS int32 (exact,
+  order-independent — the int8 compressed allreduce);
 * :meth:`max` — group max of non-negative block statistics (the shared
   quantization scale);
 * :meth:`count` — participants per group (a static Python number when no
@@ -20,11 +20,11 @@ is the ENCODED payload:
 * :meth:`gathered` — ragged/packed forms that have no elementwise reduce
   (sign majority vote): hand ``fn`` the group-stacked wire arrays with the
   member axis at -2, plus the member participation mask (or None);
-* :meth:`sparse_mean` — top-k (values, indices) payloads: a fused
-  decode-reduce into the dense mean.
+* :meth:`sparse_mean` — top-k (values, indices) payloads: a scatter-add
+  decode-reduce (:func:`topk_decode_reduce`) into the dense mean.
 
 Three implementations keep the exactness ladder intact: ``SimWireOps``
-(in-array reshape reduces over the worker axis — the reference arithmetic),
+(in-array row-slice reduces over the worker axis — the reference arithmetic),
 ``MeshWireOps`` (named-axis collectives inside ``shard_map`` — psum/pmax on
 the wire dtype, ``all_gather`` for ragged forms), and ``ExactWireOps``
 (gather the full worker block, replay ``SimWireOps``, select this shard's
@@ -44,6 +44,17 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def topk_decode_reduce(vals: jax.Array, idx: jax.Array, *,
+                       size: int) -> jax.Array:
+    """(vals (M, K), idx int32 (M, K)) -> dense sum f32 (size,): the top-k
+    collective's receive side, M gathered sparse payloads scatter-added into
+    one dense buffer without materializing M dense payloads.  A plain
+    scatter-add on every backend (a VMEM-tiled kernel would have to sort the
+    ~L/16 gathered entries per worker first)."""
+    return jnp.zeros((size,), jnp.float32).at[idx.reshape(-1)].add(
+        vals.reshape(-1).astype(jnp.float32))
+
+
 def _prod(xs) -> int:
     out = 1
     for x in xs:
@@ -55,7 +66,13 @@ class SimWireOps:
     """In-array reductions over the leading worker axis (the sim executor's
     form).  ``group_sizes`` + ``level`` define the member axis exactly as
     ``UniformTopology.aggregate`` does: a level-ℓ sync reduces over the
-    trailing ``prod(group_sizes[ℓ-1:])`` workers of each outer group."""
+    trailing ``prod(group_sizes[ℓ-1:])`` workers of each outer group.
+
+    Those members are a contiguous run of rows, so each group is reduced as
+    its own row slice of the unreshaped ``(n, ...)`` input.  Splitting the
+    worker axis of a flat ``(n, L)`` bucket into ``(outer, members, L)``
+    instead is a relayout the TPU compiler takes minutes over at a full
+    model's L; row slices compile in seconds at any L."""
 
     backend = "sim"
 
@@ -67,20 +84,30 @@ class SimWireOps:
         self.outer = _prod(self.gs) // self.members
 
     # -- shared shaping -----------------------------------------------------
-    def _axes(self) -> Tuple[int, ...]:
-        return tuple(range(self.level - 1, len(self.gs)))
-
-    def _shaped(self, x):
-        return x.reshape(self.gs + x.shape[1:])
-
-    def _wr(self, shaped, dtype):
+    def _weights(self, dtype):
+        """The (n,) participation mask in ``dtype``, or None."""
         if self.mask is None:
             return None
-        w = jnp.asarray(self.mask).astype(dtype)
-        return w.reshape(self.gs + (1,) * (shaped.ndim - len(self.gs)))
+        return jnp.asarray(self.mask).astype(dtype).reshape(-1)
 
-    def _restore(self, out, shaped_shape, flat_shape):
-        return jnp.broadcast_to(out, shaped_shape).reshape(flat_shape)
+    def _per_group(self, fn, arrays, w):
+        """``fn(*slices, w_slice)`` on each group's ``(members, ...)`` row
+        slices (``w_slice`` the group's (members,) weights or None); each
+        result keeps a leading axis of 1 and is broadcast back over its
+        member rows."""
+        m = self.members
+        outs = []
+        for o in range(self.outer):
+            rows = slice(o * m, (o + 1) * m)
+            out = fn(*(a[rows] for a in arrays),
+                     None if w is None else w[rows])
+            outs.append(jnp.broadcast_to(out, (m,) + out.shape[1:]))
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+
+    @staticmethod
+    def _col(w, ndim):
+        """(members,) weights -> (members, 1, ...) against an ndim array."""
+        return None if w is None else w.reshape((-1,) + (1,) * (ndim - 1))
 
     # -- the reduction vocabulary -------------------------------------------
     def mean(self, x):
@@ -88,35 +115,35 @@ class SimWireOps:
         MeanAggregator(f32): encode=astype(f32), axis_weighted_mean,
         decode=astype back, broadcast over the group rows."""
         from repro.core.aggregators import axis_weighted_mean
-        shaped = self._shaped(x)
-        wr = self._wr(shaped, jnp.float32)
-        out = axis_weighted_mean(shaped.astype(jnp.float32), wr,
-                                 self._axes(), jnp.float32)
-        out = out.astype(x.dtype)
-        return self._restore(out, shaped.shape, x.shape)
 
-    def sum(self, x):
-        """Dtype-preserving masked group sum — int32 payloads accumulate in
-        int32 (exact, reassociation-free), which is the widened-accumulator
-        rule of the int8 compressed allreduce."""
-        shaped = self._shaped(x)
-        shape = shaped.shape
-        wr = self._wr(shaped, x.dtype)
-        if wr is not None:
-            shaped = shaped * wr
-        out = shaped.sum(axis=self._axes(), keepdims=True, dtype=x.dtype)
-        return self._restore(out, shape, x.shape)
+        def one(g, w):
+            out = axis_weighted_mean(g.astype(jnp.float32),
+                                     self._col(w, g.ndim), (0,), jnp.float32)
+            return out.astype(x.dtype)
+        return self._per_group(one, (x,), self._weights(jnp.float32))
+
+    def sum(self, x, dtype=None):
+        """Masked group sum in ``dtype`` (default: the payload's own) — an
+        int8 payload widened to int32 accumulates in int32 (exact,
+        reassociation-free), which is the widened-accumulator rule of the
+        int8 compressed allreduce."""
+        dtype = x.dtype if dtype is None else jnp.dtype(dtype)
+
+        def one(g, w):
+            g = g.astype(dtype)
+            if w is not None:
+                g = g * self._col(w, g.ndim)
+            return g.sum(axis=0, keepdims=True, dtype=dtype)
+        return self._per_group(one, (x,), self._weights(dtype))
 
     def max(self, x):
         """Masked group max of NON-NEGATIVE statistics (block amax); masked
         rows are zeroed, never lowering a real max below 0."""
-        shaped = self._shaped(x)
-        shape = shaped.shape
-        wr = self._wr(shaped, x.dtype)
-        if wr is not None:
-            shaped = shaped * wr
-        out = shaped.max(axis=self._axes(), keepdims=True)
-        return self._restore(out, shape, x.shape)
+        def one(g, w):
+            if w is not None:
+                g = g * self._col(w, g.ndim)
+            return g.max(axis=0, keepdims=True)
+        return self._per_group(one, (x,), self._weights(x.dtype))
 
     def count(self):
         """Participants per group: a static Python float when unmasked (no
@@ -124,31 +151,26 @@ class SimWireOps:
         if self.mask is None:
             return float(self.members)
         from repro.core.aggregators import denominator_floor
-        m = jnp.asarray(self.mask).astype(jnp.float32).reshape(self.gs)
-        c = m.sum(axis=self._axes(), keepdims=True, dtype=jnp.float32)
-        c = jnp.broadcast_to(c, self.gs).reshape(-1, 1)
+        w = self._weights(jnp.float32)[:, None]
+        c = self._per_group(
+            lambda g, _: g.sum(axis=0, keepdims=True, dtype=jnp.float32),
+            (w,), None)
         return jnp.maximum(c, denominator_floor(jnp.float32))
 
     def gathered(self, fn: Callable, *arrays):
-        """Group-stack the (n, ...) wire arrays to (outer, members, ...),
-        call ``fn(*stacked, member_mask)`` (member axis at -2; mask is
-        (outer, members) or None), broadcast its (outer, ...) result back
-        over the member rows."""
-        g = [a.reshape((self.outer, self.members) + a.shape[1:])
-             for a in arrays]
-        wmask = None
-        if self.mask is not None:
-            wmask = jnp.asarray(self.mask).astype(jnp.float32).reshape(
-                self.outer, self.members)
-        out = fn(*g, wmask)
-        out = jnp.broadcast_to(out[:, None],
-                               (self.outer, self.members) + out.shape[1:])
-        return out.reshape((self.outer * self.members,) + out.shape[2:])
+        """Call ``fn(*stacked, member_mask)`` once per group on its
+        ``(1, members, ...)`` row slices of the (n, ...) wire arrays (member
+        axis at -2; mask is (1, members) or None) and broadcast each
+        ``(1, ...)`` result back over the group's member rows."""
+        def one(*args):
+            *g, w = args
+            return fn(*(a[None] for a in g), None if w is None else w[None])
+        return self._per_group(one, arrays, self._weights(jnp.float32))
 
     def sparse_mean(self, vals, idx, dense):
         """Sim reference for top-k: the decoded dense payload already exists
-        locally, so the fused kernel is pointless — the group mean of the
-        dense form IS the legacy arithmetic, bitwise."""
+        locally, so a sparse decode-reduce is pointless — the group mean of
+        the dense form IS the legacy arithmetic, bitwise."""
         del vals, idx
         return self.mean(dense)
 
@@ -184,8 +206,10 @@ class MeshWireOps:
                                        self.axes, jnp.float32)
         return out.astype(x.dtype)
 
-    def sum(self, x):
+    def sum(self, x, dtype=None):
         from repro.core.aggregators import named_axis_sum
+        if dtype is not None:
+            x = x.astype(dtype)
         return named_axis_sum(x, self.axes, self._own_w(x.dtype))
 
     def max(self, x):
@@ -215,18 +239,15 @@ class MeshWireOps:
 
     def sparse_mean(self, vals, idx, dense):
         """The top-k compressed collective: ragged all-gather of the
-        (values, indices) payload + one Pallas fused decode-reduce into the
+        (values, indices) payload + one scatter-add decode-reduce into the
         dense sum, then the participant mean."""
-        from repro.kernels import ops as _ops
         vg = jax.lax.all_gather(vals, self.axes, axis=0, tiled=True)
         ig = jax.lax.all_gather(idx, self.axes, axis=0, tiled=True)
         wm = self._member_mask()
         if wm is not None:
             vg = vg * wm[:, None]
         size = int(np.prod(dense.shape[1:], dtype=np.int64))
-        acc = _ops.topk_decode_reduce(vg.reshape(-1, vg.shape[-1]),
-                                      ig.reshape(-1, ig.shape[-1]),
-                                      size=size)
+        acc = topk_decode_reduce(vg, ig, size=size)
         out = (acc / self.count()).reshape((1,) + dense.shape[1:])
         return out.astype(dense.dtype)
 
@@ -255,8 +276,8 @@ class ExactWireOps:
     def mean(self, x):
         return self._pick(self.sim.mean(self._gather(x)))
 
-    def sum(self, x):
-        return self._pick(self.sim.sum(self._gather(x)))
+    def sum(self, x, dtype=None):
+        return self._pick(self.sim.sum(self._gather(x), dtype))
 
     def max(self, x):
         return self._pick(self.sim.max(self._gather(x)))

@@ -41,7 +41,6 @@ from typing import Any, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comms.reduce import ExactWireOps, MeshWireOps, SimWireOps
@@ -717,17 +716,19 @@ class MeshExecutor(Executor):
             pspec = jax.tree.map(lambda x: self._lead_spec(x.ndim), params)
             ospec = jax.tree.map(lambda x: self._lead_spec(x.ndim), opt_state)
             cspec = jax.tree.map(lambda x: self._lead_spec(x.ndim), cstate)
-            # same check_rep policy as the round core (see _round_core)
-            kw = dict(check_rep=False) \
+            # same check_vma policy as the round core (see _round_core)
+            kw = dict(check_vma=False) \
                 if (plan.comms is not None or mask is not None) else {}
             if mask is None:
-                fn = shard_map(lambda p, o, c: shard_body(p, o, c, None),
-                               mesh=mesh, in_specs=(pspec, ospec, cspec),
-                               out_specs=(pspec, ospec, cspec), **kw)
+                fn = jax.shard_map(
+                    lambda p, o, c: shard_body(p, o, c, None), mesh=mesh,
+                    in_specs=(pspec, ospec, cspec),
+                    out_specs=(pspec, ospec, cspec), **kw)
                 return fn(params, opt_state, cstate)
-            fn = shard_map(lambda p, o, c, m: shard_body(p, o, c, m),
-                           mesh=mesh, in_specs=(pspec, ospec, cspec, P()),
-                           out_specs=(pspec, ospec, cspec), **kw)
+            fn = jax.shard_map(lambda p, o, c, m: shard_body(p, o, c, m),
+                               mesh=mesh,
+                               in_specs=(pspec, ospec, cspec, P()),
+                               out_specs=(pspec, ospec, cspec), **kw)
             return fn(params, opt_state, cstate, jnp.asarray(mask))
 
         return sync
@@ -847,11 +848,11 @@ class MeshExecutor(Executor):
             # (and the probe row — its last op is a pmean over ALL replica
             # axes) are replicated by construction, so skipping the check
             # is safe.  The stale path mixes fold deltas the same way.
-            kw = dict(check_rep=False) \
+            kw = dict(check_vma=False) \
                 if (plan.comms is not None or masked
                     or row_fn is not None or stale) else {}
             if not masked:
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda p, o, c, mb, pd, b: body(p, o, c, mb, pd, b,
                                                     None),
                     mesh=mesh,
@@ -860,7 +861,7 @@ class MeshExecutor(Executor):
                     **kw)
                 return fn(params, opt_state, cstate, mbuf, pending, stacked)
             # the mask rides in replicated: every shard reads its own entry
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda p, o, c, mb, pd, b, m: body(p, o, c, mb, pd, b, m),
                 mesh=mesh,
                 in_specs=(pspec, ospec, cspec, mspec, pendspec, bspec, P()),

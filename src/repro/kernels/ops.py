@@ -92,17 +92,6 @@ def int8_scale_quantize(x, scale, *, block: int = 256,
                                       interpret=interpret)
 
 
-def topk_decode_reduce(vals, idx, *, size: int, block: int = 256,
-                       interpret: Optional[bool] = None):
-    # per output element the sum order over sparse entries is independent of
-    # the block size, so the interpret-mode shrink never changes values
-    if interpret is None:
-        interpret = _interpret_default()
-    return _comms.topk_decode_reduce(vals, idx, size=size,
-                                     block=_comm_block(block, interpret),
-                                     interpret=interpret)
-
-
 def sign_pack(x, *, block: int = 1024, interpret: Optional[bool] = None):
     if interpret is None:
         interpret = _interpret_default()

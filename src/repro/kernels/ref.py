@@ -86,7 +86,7 @@ def int8_scale_quant_ref(x: jax.Array, scale: jax.Array,
 
 
 def topk_reduce_ref(vals: jax.Array, idx: jax.Array, size: int) -> jax.Array:
-    """Scatter-add oracle for the fused top-k decode-reduce: (M, K) sparse
+    """Scatter-add oracle for the top-k decode-reduce: (M, K) sparse
     payloads summed into one dense (size,) f32 buffer."""
     return jnp.zeros((size,), jnp.float32).at[idx.ravel()].add(
         vals.ravel().astype(jnp.float32))

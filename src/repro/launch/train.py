@@ -1,9 +1,13 @@
 """End-to-end H-SGD training driver.
 
-Runs on whatever devices exist (CPU smoke -> TPU pods): builds the model from
---arch (reduced variant on CPU), an H-SGD topology (--workers/--groups/--G/--I,
-optionally --levels for multi-level), the synthetic token pipeline, and trains
-with periodic checkpointing + divergence telemetry.
+Runs on whatever devices exist — the host CPU (with --reduced), one TPU chip
+(qwen2-0.5b at its published widths: see chip_smoke.py), a four-chip host
+with --backend mesh: builds the model from --arch, an H-SGD topology
+(--workers/--groups/--G/--I, optionally --levels for multi-level), the
+synthetic token pipeline, and trains with periodic checkpointing +
+divergence telemetry.  Compiled programs persist across runs in
+$JAX_COMPILATION_CACHE_DIR, else in <repo>/.jax_cache
+(repro.launch.cache).
 
 Execution goes through the schedule-compiled round executor (``run_rounds``):
 each pure-local block is one fused dispatch, with the schedule additionally
@@ -59,6 +63,7 @@ from repro.configs import get_config, reduced
 from repro.core import (EngineConfig, HSGD, HierarchySpec, all_divergences,
                         contiguous, make_topology, per_worker_grads)
 from repro.data import TokenStream
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import cosine, momentum, sgd
 
@@ -285,6 +290,7 @@ def main(argv=None):
     if (args.straggler or args.deadline) and not args.runtime:
         ap.error("--straggler/--deadline need --runtime (the simulated "
                  "clock they perturb)")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -29,15 +29,12 @@ def constrain_acts(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Optional residual-stream sharding constraint (cfg.act_pspec), a §Perf
     knob: pins the layout GSPMD must keep between layers instead of letting
     it re-shard (which showed up as per-layer activation all-gathers in the
-    baseline HLO)."""
-    if cfg.act_pspec is None:
+    baseline HLO).  A no-op when no mesh is set in context (``jax.set_mesh``);
+    under one, a constraint the mesh cannot satisfy raises."""
+    if cfg.act_pspec is None or jax.sharding.get_abstract_mesh().empty:
         return x
     from jax.sharding import PartitionSpec as P
-    spec = P(*cfg.act_pspec)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x  # no mesh context (CPU tests) -> no-op
+    return jax.lax.with_sharding_constraint(x, P(*cfg.act_pspec))
 
 
 # --------------------------------------------------------------------------

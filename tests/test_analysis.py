@@ -33,15 +33,14 @@ from repro.optim.optimizers import sgd
 # walker
 # ---------------------------------------------------------------------------
 def test_walker_records_collectives_with_axes_and_payload():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    f = shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
-                  in_specs=P("x"), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
+                      in_specs=P("x"), out_specs=P(), check_vma=False)
     summary = trace(jax.jit(f), jnp.ones((1, 4), jnp.float32))
     assert summary.collective_count == 1
     op = summary.collectives[0]
-    assert op.primitive in ("psum", "psum2")
+    assert op.primitive in ("psum", "psum2", "psum_invariant")
     assert op.axes == ("x",)
     assert op.dtypes == ("float32",)
     assert op.elements == 4 and op.nbytes == 16
@@ -54,7 +53,9 @@ def test_walker_records_host_callbacks():
         return x * 2
 
     summary = trace(g, jnp.ones(3))
-    assert [o.primitive for o in summary.callbacks] == ["debug_callback"]
+    # jax.debug.print binds debug_print on JAX >= 0.9, debug_callback before
+    assert [o.primitive for o in summary.callbacks] in (["debug_callback"],
+                                                        ["debug_print"])
 
 
 def test_walker_descends_into_scan_bodies():
@@ -264,7 +265,7 @@ def test_live_debug_print_in_loss_caught_by_r3():
                     "y": jnp.zeros((8, 4), jnp.int32)}
     rep = audit_engine(eng, state, bf, run=False)  # trace only, no printing
     assert sorted({f.rule for f in rep.unwaived}) == ["R3"]
-    assert any("debug_callback" in c
+    assert any("debug_callback" in c or "debug_print" in c
                for r in rep.rounds.values() for c in r.callbacks)
 
 

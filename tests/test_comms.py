@@ -17,11 +17,10 @@ from repro.comms.codecs import COMPRESSORS
 from repro.core import (HSGD, GroupedTopology, HierarchySpec, SyncEvent,
                         contiguous, make_aggregator, make_topology)
 from repro.data import FederatedDataset, label_shard_partition, make_classification
+from repro.comms.reduce import topk_decode_reduce
 from repro.kernels.comms import (int8_dequantize, int8_quantize,
-                                 int8_scale_quantize, sign_pack, sign_unpack,
-                                 topk_decode_reduce)
-from repro.kernels.ref import (int8_ref, int8_scale_quant_ref, sign_ref,
-                               topk_reduce_ref)
+                                 int8_scale_quantize, sign_pack, sign_unpack)
+from repro.kernels.ref import int8_ref, int8_scale_quant_ref, sign_ref
 from repro.models import SimpleConfig, SimpleModel
 from repro.optim import sgd
 
@@ -148,28 +147,46 @@ def test_int8_scale_quantize_matches_ref(r, c, blk):
 @pytest.mark.parametrize("m,k,size,blk", [(8, 4, 100, 32), (1, 1, 7, 8),
                                           (16, 15, 244, 64), (3, 10, 64, 64)])
 def test_topk_decode_reduce_matches_ref(m, k, size, blk):
-    """The fused Pallas decode-reduce behind the top-k ragged all-gather:
-    M sparse (values, indices) payloads scatter-added into one dense
-    (size,) f32 sum.  With unique indices the match against the jnp scatter
-    oracle is bitwise (each output element is a single payload value);
-    colliding indices accumulate, in a summation order that may differ from
-    the oracle's scatter order by f32 rounding (1 ulp)."""
+    """The scatter-add decode-reduce behind the top-k ragged all-gather:
+    M sparse (values, indices) payloads summed into one dense (size,) f32
+    buffer.  It is the same jnp scatter as ``kernels/ref.py::topk_reduce_ref``,
+    so it is held to numpy's unbuffered ``np.add.at`` instead: distinct
+    indices land bitwise (each output element is one payload value);
+    colliding indices accumulate up to f32 summation order; a masked-out
+    payload (values zeroed, as ``MeshWireOps.sparse_mean`` does) contributes
+    nothing; and a gather delivered in ``blk``-entry pieces sums to the
+    whole."""
     rng = np.random.default_rng(5)
-    vals = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
-    uniq = jnp.asarray(rng.permutation(size)[:min(m * k, size)], jnp.int32)
+    vals = rng.normal(size=(m, k)).astype(np.float32)
+
+    def oracle(v, i):
+        out = np.zeros(size, np.float32)
+        np.add.at(out, i.reshape(-1), v.reshape(-1))
+        return out
+
+    uniq = rng.permutation(size)[:min(m * k, size)].astype(np.int32)
     if uniq.size == m * k:  # all indices distinct -> bitwise
-        out = topk_decode_reduce(vals, uniq.reshape(m, k), size=size,
-                                 block=blk, interpret=True)
+        out = topk_decode_reduce(jnp.asarray(vals),
+                                 jnp.asarray(uniq.reshape(m, k)), size=size)
         assert out.shape == (size,) and out.dtype == jnp.float32
-        np.testing.assert_array_equal(
-            np.asarray(out),
-            np.asarray(topk_reduce_ref(vals, uniq.reshape(m, k), size)))
-    idx = jnp.asarray(rng.integers(0, size, size=(m, k)), jnp.int32)
-    out = topk_decode_reduce(vals, idx, size=size, block=blk, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      oracle(vals, uniq.reshape(m, k)))
+    idx = rng.integers(0, size, size=(m, k)).astype(np.int32)
+    out = topk_decode_reduce(jnp.asarray(vals), jnp.asarray(idx), size=size)
     assert out.shape == (size,) and out.dtype == jnp.float32
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(topk_reduce_ref(vals, idx, size)),
-        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out), oracle(vals, idx),
+                               rtol=1e-6, atol=1e-6)
+    mask = (np.arange(m) % 2 == 0).astype(np.float32)[:, None]
+    masked = topk_decode_reduce(jnp.asarray(vals * mask), jnp.asarray(idx),
+                                size=size)
+    np.testing.assert_allclose(np.asarray(masked),
+                               oracle(vals[::2], idx[::2]),
+                               rtol=1e-6, atol=1e-6)
+    pieces = sum(topk_decode_reduce(jnp.asarray(vals[:, i:i + blk]),
+                                    jnp.asarray(idx[:, i:i + blk]), size=size)
+                 for i in range(0, k, blk))
+    np.testing.assert_allclose(np.asarray(pieces), np.asarray(out),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_comm_kernels_public_entry_points():

@@ -112,3 +112,48 @@ def test_multilevel_driver_smoke():
     # comms on: cumulative wire accounting rides the telemetry records, and
     # 8 steps of (8,4,2) hit L3 twice, L2 once, L1 once
     assert hist[-1]["wire_cum_bytes"] > 0
+
+
+def test_compile_cache_defers_to_env(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own handling stands."""
+    from repro.launch.cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_repo_dir(monkeypatch):
+    """Unset, the cache is <repo>/.jax_cache — never a temporary path."""
+    from pathlib import Path
+    from repro.launch.cache import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        repo = Path(__file__).resolve().parents[1]
+        assert Path(path) == repo / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("where", ["cpu_only", "script_alone"])
+def test_chip_smoke_refuses_without_tpu(where, tmp_path):
+    """chip_smoke.py exits non-zero and prints no result on a CPU-only
+    host, and in a directory holding nothing else of the repo."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+    script = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    if where == "script_alone":
+        script = Path(shutil.copy(script, tmp_path))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, str(script)], cwd=script.parent,
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "chip_smoke: FAILED" in out.stderr

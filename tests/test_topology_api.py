@@ -386,14 +386,13 @@ def test_denominator_guard_survives_half_precision_all_masked():
                                 jnp.float16)
     assert np.isfinite(np.asarray(out, jnp.float32)).all()
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    f = shard_map(
+    f = jax.shard_map(
         lambda vv, ww: named_axis_weighted_mean(vv, ww[0], ("x",),
                                                 jnp.float16),
         mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"),
-        check_rep=False)
+        check_vma=False)
     out = f(jnp.ones((1, 3), jnp.float16), jnp.zeros((1,), jnp.float16))
     assert np.isfinite(np.asarray(out, jnp.float32)).all()
 

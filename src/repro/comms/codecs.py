@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.comms.wire import WireArray
 from repro.kernels import comms as _kernels
 from repro.kernels.ops import _interpret_default
+from repro.obs.spans import DECODE, ENCODE
 
 
 class Compressor(abc.ABC):
@@ -77,7 +78,10 @@ class Compressor(abc.ABC):
             u = x
         else:
             u = x.astype(residual.dtype) + residual
-        sent = self.decode(self.encode(u), u)
+        with jax.named_scope(ENCODE):
+            wire = self.encode(u)
+        with jax.named_scope(DECODE):
+            sent = self.decode(wire, u)
         if residual is None or not self.stateful:
             return sent.astype(x.dtype), None
         return sent.astype(x.dtype), (u - sent.astype(u.dtype))
@@ -171,17 +175,21 @@ class Int8Compressor(Compressor):
         r, c = x2.shape
         nb = -(-c // self.block)
         pad = nb * self.block - c
-        amax = jnp.pad(jnp.abs(x2), ((0, 0), (0, pad))) \
-            .reshape(r, nb, self.block).max(axis=-1)          # (r, nb)
+        with jax.named_scope(ENCODE):
+            amax = jnp.pad(jnp.abs(x2), ((0, 0), (0, pad))) \
+                .reshape(r, nb, self.block).max(axis=-1)      # (r, nb)
         scale = ops.max(amax.astype(jnp.float32)) / 127.0      # group scale
-        q = _kernels.int8_scale_quantize(
-            x2, scale, block=self.block, interpret=_interpret_default())
+        with jax.named_scope(ENCODE):
+            q = _kernels.int8_scale_quantize(
+                x2, scale, block=self.block, interpret=_interpret_default())
         qsum = ops.sum(q, jnp.int32)
-        y = (jnp.pad(qsum.astype(jnp.float32), ((0, 0), (0, pad)))
-             .reshape(r, nb, self.block) * scale[..., None]) \
-            .reshape(r, nb * self.block)[:, :c]
-        y = y / ops.count()
-        return y.reshape(x.shape).astype(x.dtype), None
+        count = ops.count()
+        with jax.named_scope(DECODE):
+            y = (jnp.pad(qsum.astype(jnp.float32), ((0, 0), (0, pad)))
+                 .reshape(r, nb, self.block) * scale[..., None]) \
+                .reshape(r, nb * self.block)[:, :c]
+            y = y / count
+            return y.reshape(x.shape).astype(x.dtype), None
 
     def lowered_sync_ops(self, backend):
         # mesh: pmax on the scales + psum on the int32 payload; sim: the
@@ -232,9 +240,11 @@ class SignCompressor(Compressor):
         x2 = _rows(x)
         c = x2.shape[1]
         block = self.block
-        bits, scale = _kernels.sign_pack(
-            x2, block=block, interpret=_interpret_default())
+        with jax.named_scope(ENCODE):
+            bits, scale = _kernels.sign_pack(
+                x2, block=block, interpret=_interpret_default())
 
+        @jax.named_scope(DECODE)
         def fuse(bits_g, scale_g, wmask):
             # member axis at -2 (WireOps.gathered contract)
             from repro.core.aggregators import denominator_floor
@@ -321,8 +331,10 @@ class TopKCompressor(Compressor):
             u = x
         else:
             u = x.astype(residual.dtype) + residual
-        wire = self.encode(u)
-        sent = self.decode(wire, u)
+        with jax.named_scope(ENCODE):
+            wire = self.encode(u)
+            # what the receivers will rebuild, for the residual
+            sent = self.decode(wire, u)
         new_res = None
         if residual is not None:
             new_res = u - sent.astype(u.dtype)

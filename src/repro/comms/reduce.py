@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.spans import COLLECTIVE, DECODE
+
 
 def topk_decode_reduce(vals: jax.Array, idx: jax.Array, *,
                        size: int) -> jax.Array:
@@ -201,39 +203,45 @@ class MeshWireOps:
         """The aggregator's axis-collective mean (same arithmetic the
         legacy identity sync lowered to: one pmean per buffer)."""
         from repro.core.aggregators import named_axis_weighted_mean
-        out = named_axis_weighted_mean(x.astype(jnp.float32),
-                                       self._own_w(jnp.float32),
-                                       self.axes, jnp.float32)
+        with jax.named_scope(COLLECTIVE):
+            out = named_axis_weighted_mean(x.astype(jnp.float32),
+                                           self._own_w(jnp.float32),
+                                           self.axes, jnp.float32)
         return out.astype(x.dtype)
 
     def sum(self, x, dtype=None):
         from repro.core.aggregators import named_axis_sum
         if dtype is not None:
             x = x.astype(dtype)
-        return named_axis_sum(x, self.axes, self._own_w(x.dtype))
+        with jax.named_scope(COLLECTIVE):
+            return named_axis_sum(x, self.axes, self._own_w(x.dtype))
 
     def max(self, x):
         from repro.core.aggregators import named_axis_max
-        return named_axis_max(x, self.axes, self._own_w(x.dtype))
+        with jax.named_scope(COLLECTIVE):
+            return named_axis_max(x, self.axes, self._own_w(x.dtype))
 
     def count(self):
         if self.mask is None:
             return float(self.members)
         from repro.core.aggregators import denominator_floor
-        c = jax.lax.psum(self._own_w(jnp.float32), self.axes)
+        with jax.named_scope(COLLECTIVE):
+            c = jax.lax.psum(self._own_w(jnp.float32), self.axes)
         return jnp.maximum(c, denominator_floor(jnp.float32))
 
     def _member_mask(self):
         if self.mask is None:
             return None
-        return jax.lax.all_gather(self._own_w(jnp.float32), self.axes)
+        with jax.named_scope(COLLECTIVE):
+            return jax.lax.all_gather(self._own_w(jnp.float32), self.axes)
 
     def gathered(self, fn: Callable, *arrays):
         """all_gather each (1, ...) wire array over the syncing axes to
         (members, ...) — the member axis lands at -2 because the per-shard
         leading worker axis of size 1 is what gets tiled."""
-        g = [jax.lax.all_gather(a, self.axes, axis=0, tiled=True)
-             for a in arrays]
+        with jax.named_scope(COLLECTIVE):
+            g = [jax.lax.all_gather(a, self.axes, axis=0, tiled=True)
+                 for a in arrays]
         out = fn(*g, self._member_mask())
         return out[None]
 
@@ -241,15 +249,18 @@ class MeshWireOps:
         """The top-k compressed collective: ragged all-gather of the
         (values, indices) payload + one scatter-add decode-reduce into the
         dense sum, then the participant mean."""
-        vg = jax.lax.all_gather(vals, self.axes, axis=0, tiled=True)
-        ig = jax.lax.all_gather(idx, self.axes, axis=0, tiled=True)
+        with jax.named_scope(COLLECTIVE):
+            vg = jax.lax.all_gather(vals, self.axes, axis=0, tiled=True)
+            ig = jax.lax.all_gather(idx, self.axes, axis=0, tiled=True)
         wm = self._member_mask()
-        if wm is not None:
-            vg = vg * wm[:, None]
-        size = int(np.prod(dense.shape[1:], dtype=np.int64))
-        acc = topk_decode_reduce(vg, ig, size=size)
-        out = (acc / self.count()).reshape((1,) + dense.shape[1:])
-        return out.astype(dense.dtype)
+        count = self.count()
+        with jax.named_scope(DECODE):
+            if wm is not None:
+                vg = vg * wm[:, None]
+            size = int(np.prod(dense.shape[1:], dtype=np.int64))
+            acc = topk_decode_reduce(vg, ig, size=size)
+            out = (acc / count).reshape((1,) + dense.shape[1:])
+            return out.astype(dense.dtype)
 
 
 class ExactWireOps:
@@ -267,7 +278,8 @@ class ExactWireOps:
         self.sim = SimWireOps(group_sizes, level, mask)
 
     def _gather(self, x):
-        return jax.lax.all_gather(x, self.rep, axis=0, tiled=True)
+        with jax.named_scope(COLLECTIVE):
+            return jax.lax.all_gather(x, self.rep, axis=0, tiled=True)
 
     def _pick(self, out):
         return jax.lax.dynamic_index_in_dim(out, self.widx, axis=0,

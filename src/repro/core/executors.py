@@ -48,6 +48,7 @@ from repro.core.aggregators import Aggregator, flat_worker_index
 from repro.core.hsgd import (HSGDState, Round, StaleSnap, StaleSlot,
                              _merge_moments, _moments_only)
 from repro.core.topology import SyncEvent
+from repro.obs.spans import COLLECTIVE, LOCAL, SYNC_FOLD, sync_scope
 
 
 class Executor(abc.ABC):
@@ -254,38 +255,39 @@ class SimExecutor(Executor):
         receive — they were still computing when the barrier closed, so they
         keep their exact post-update params, opt state and unconsumed comms
         residuals (the elastic-participation contract; tested)."""
-        plan = self.plan
-        reduce_fn = lambda tree: plan.topology.aggregate(tree, event,
-                                                         mask=mask)
-        wire = SimWireOps(plan.topology.spec.group_sizes, event.level,
-                          mask) if _wire_eligible(plan, event) else None
-        new_p, new_o, new_c = _apply_sync(plan, reduce_fn, params, opt_state,
-                                          cstate, wire=wire)
-        if drop:
-            keep = jnp.asarray(mask).astype(bool)
-            new_p = _keep_rows(keep, new_p, params)
-            new_o = _keep_rows(keep, new_o, opt_state)
-            if cstate is not None:
-                new_c = _keep_rows(keep, new_c, cstate)
-        if plan.comms is not None:
-            # topology.aggregate keeps non-participants' rows untouched, but
-            # the comms path hands it codec-roundtripped payloads — restore
-            # the true state (and unconsumed residual) of workers a
-            # partial-group event did not sync
-            part = plan.topology.participants(event)
-            if part is not None:
-                keep = jnp.asarray(part)
+        with jax.named_scope(sync_scope(event.level)):
+            plan = self.plan
+            reduce_fn = lambda tree: plan.topology.aggregate(tree, event,
+                                                             mask=mask)
+            wire = SimWireOps(plan.topology.spec.group_sizes, event.level,
+                              mask) if _wire_eligible(plan, event) else None
+            new_p, new_o, new_c = _apply_sync(plan, reduce_fn, params,
+                                              opt_state, cstate, wire=wire)
+            if drop:
+                keep = jnp.asarray(mask).astype(bool)
                 new_p = _keep_rows(keep, new_p, params)
                 new_o = _keep_rows(keep, new_o, opt_state)
                 if cstate is not None:
                     new_c = _keep_rows(keep, new_c, cstate)
-            if mask is not None and cstate is not None:
-                # runtime-masked workers still RECEIVE the aggregate
-                # (Algorithm 1) but transmitted nothing: their
-                # error-feedback residual must not be consumed
-                new_c = _keep_rows(jnp.asarray(mask).astype(bool),
-                                   new_c, cstate)
-        return new_p, new_o, new_c
+            if plan.comms is not None:
+                # topology.aggregate keeps non-participants' rows untouched,
+                # but the comms path hands it codec-roundtripped payloads —
+                # restore the true state (and unconsumed residual) of
+                # workers a partial-group event did not sync
+                part = plan.topology.participants(event)
+                if part is not None:
+                    keep = jnp.asarray(part)
+                    new_p = _keep_rows(keep, new_p, params)
+                    new_o = _keep_rows(keep, new_o, opt_state)
+                    if cstate is not None:
+                        new_c = _keep_rows(keep, new_c, cstate)
+                if mask is not None and cstate is not None:
+                    # runtime-masked workers still RECEIVE the aggregate
+                    # (Algorithm 1) but transmitted nothing: their
+                    # error-feedback residual must not be consumed
+                    new_c = _keep_rows(jnp.asarray(mask).astype(bool),
+                                       new_c, cstate)
+            return new_p, new_o, new_c
 
     def sync_fn(self, event: SyncEvent):
         def sync(params, opt_state, cstate, mask=None):
@@ -312,42 +314,44 @@ class SimExecutor(Executor):
         ``staleness`` (only when requested) is the probe scalar: worker-mean
         ‖applied − fresh‖² against the codec-free barrier aggregate of the
         pre-fold params — 0 at warm-up and flush boundaries."""
-        plan = self.plan
-        agg_opt = plan.aggregate_opt_state
-        pre_params = params
-        pending = dict(pending)
-        for op in ops:
-            slot = pending[op.level]
-            snaps, res = list(slot.snaps), slot.residual
-            s = len(snaps)
-            for j in range(op.n_fold):
-                snap = snaps[s - op.warm + j]
-                params = _stale_delta(params, snap.agg, snap.params)
-                if agg_opt:
-                    opt_state = _merge_moments(opt_state, _stale_delta(
-                        _moments_only(opt_state), snap.agg_opt, snap.opt))
-            if op.snapshot:
-                base_o = _moments_only(opt_state) if agg_opt else {}
-                new_p, new_o, res = self._apply_event(
-                    params, base_o, res, SyncEvent(level=op.level),
-                    mask=mask)
-                snaps = snaps[1:] + [StaleSnap(
-                    params, base_o, new_p,
-                    _moments_only(new_o) if agg_opt else {})]
-            pending[op.level] = StaleSlot(tuple(snaps), res)
-        stale_val = None
-        if want_staleness:
-            own = ops[-1] if ops and ops[-1].snapshot else None
-            if own is not None and own.n_fold:
-                fresh = plan.topology.aggregate(pre_params, event, mask=mask)
-                d2 = sum(jnp.sum(jnp.square((a - f).astype(jnp.float32))
-                                 .reshape(a.shape[0], -1), axis=1)
-                         for a, f in zip(jax.tree.leaves(params),
-                                         jax.tree.leaves(fresh)))
-                stale_val = jnp.mean(d2)
-            else:
-                stale_val = jnp.zeros((), jnp.float32)
-        return params, opt_state, pending, stale_val
+        with jax.named_scope(SYNC_FOLD):
+            plan = self.plan
+            agg_opt = plan.aggregate_opt_state
+            pre_params = params
+            pending = dict(pending)
+            for op in ops:
+                slot = pending[op.level]
+                snaps, res = list(slot.snaps), slot.residual
+                s = len(snaps)
+                for j in range(op.n_fold):
+                    snap = snaps[s - op.warm + j]
+                    params = _stale_delta(params, snap.agg, snap.params)
+                    if agg_opt:
+                        opt_state = _merge_moments(opt_state, _stale_delta(
+                            _moments_only(opt_state), snap.agg_opt, snap.opt))
+                if op.snapshot:
+                    base_o = _moments_only(opt_state) if agg_opt else {}
+                    new_p, new_o, res = self._apply_event(
+                        params, base_o, res, SyncEvent(level=op.level),
+                        mask=mask)
+                    snaps = snaps[1:] + [StaleSnap(
+                        params, base_o, new_p,
+                        _moments_only(new_o) if agg_opt else {})]
+                pending[op.level] = StaleSlot(tuple(snaps), res)
+            stale_val = None
+            if want_staleness:
+                own = ops[-1] if ops and ops[-1].snapshot else None
+                if own is not None and own.n_fold:
+                    fresh = plan.topology.aggregate(pre_params, event,
+                                                    mask=mask)
+                    d2 = sum(jnp.sum(jnp.square((a - f).astype(jnp.float32))
+                                     .reshape(a.shape[0], -1), axis=1)
+                             for a, f in zip(jax.tree.leaves(params),
+                                             jax.tree.leaves(fresh)))
+                    stale_val = jnp.mean(d2)
+                else:
+                    stale_val = jnp.zeros((), jnp.float32)
+            return params, opt_state, pending, stale_val
 
     def _probe_row_fn(self, event: Optional[SyncEvent]):
         """The in-graph divergence probe for sync steps (None when metrics
@@ -369,8 +373,9 @@ class SimExecutor(Executor):
         stale_channel = row_fn is not None and self.plan.metrics.staleness
 
         def step(state: HSGDState, batch, mask=None):
-            params, opt_state, metrics = jax.vmap(local_update)(
-                state.params, state.opt_state, batch)
+            with jax.named_scope(LOCAL):
+                params, opt_state, metrics = jax.vmap(local_update)(
+                    state.params, state.opt_state, batch)
             cstate, mbuf = state.comms, state.metrics
             if masked:
                 # non-participating workers keep their previous state
@@ -426,8 +431,11 @@ class SimExecutor(Executor):
                 return (params, opt_state), jax.tree.map(
                     lambda m: m.mean(), metrics)
 
-            (params, opt_state), metrics = jax.lax.scan(
-                body, (state.params, state.opt_state), stacked)
+            # what XLA's loop passes rebuild keeps the loop's name, so the
+            # whole local block is named, not only the step inside it
+            with jax.named_scope(LOCAL):
+                (params, opt_state), metrics = jax.lax.scan(
+                    body, (state.params, state.opt_state), stacked)
             cstate, mbuf, pending = state.comms, state.metrics, state.pending
             if rnd.event is not None:
                 div_row = row_fn(params) \
@@ -585,58 +593,64 @@ class MeshExecutor(Executor):
             members = math.prod(self.mesh.shape[a] for a in ev_axes)
 
         def apply_event(params, opt_state, cstate, mask, widx):
-            wire = None
-            if wire_ok:
-                # exact mode replays the SIM wire arithmetic on the gathered
-                # block (bitwise vs SimExecutor); production lowers the
-                # codec's collective over exactly the event's mesh axes
-                wire = ExactWireOps(rep, widx, topo.spec.group_sizes,
-                                    event.level, mask) if self.exact else \
-                    MeshWireOps(ev_axes, members, mask, widx)
-            if self.exact:
-                # replay the ENTIRE sim reduce on the gathered worker block
-                # (same shapes, same weight combination -> bitwise), then
-                # select this shard's own row
-                def reduce_fn(tree):
-                    g = jax.tree.map(
-                        lambda x: jax.lax.all_gather(x, rep, axis=0,
-                                                     tiled=True), tree)
-                    out = topo.aggregate(g, event, mask=mask)
-                    return jax.tree.map(
-                        lambda x: jax.lax.dynamic_index_in_dim(
-                            x, widx, axis=0, keepdims=True), out)
-            else:
-                w = None if mask is None else mask.astype(acc)[widx]
-                if wvec is not None:
-                    ws = jnp.asarray(wvec)[widx]
-                    w = ws if w is None else w * ws
-                one = lambda x: topo.shard_aggregate(
-                    x, rep, event, worker_index=widx, weight=w)
-                reduce_fn = lambda tree: jax.tree.map(one, tree)
-            new_p, new_o, new_c = _apply_sync(plan, reduce_fn, params,
-                                              opt_state, cstate, wire=wire)
-            if plan.comms is not None:
-                # same restores as SimExecutor._apply_event, per shard: the
-                # comms path hands the reduce codec-roundtripped payloads,
-                # so workers a partial-group event did not sync get their
-                # true state back, and a masked-out worker's error-feedback
-                # residual is not consumed
-                if part is not None:
-                    keep = jnp.asarray(part)[widx]
+            with jax.named_scope(sync_scope(event.level)):
+                wire = None
+                if wire_ok:
+                    # exact mode replays the SIM wire arithmetic on the
+                    # gathered block (bitwise vs SimExecutor); production
+                    # lowers the codec's collective over exactly the
+                    # event's mesh axes
+                    wire = ExactWireOps(rep, widx, topo.spec.group_sizes,
+                                        event.level, mask) if self.exact else \
+                        MeshWireOps(ev_axes, members, mask, widx)
+                if self.exact:
+                    # replay the ENTIRE sim reduce on the gathered worker block
+                    # (same shapes, same weight combination -> bitwise), then
+                    # select this shard's own row
+                    def reduce_fn(tree):
+                        with jax.named_scope(COLLECTIVE):
+                            g = jax.tree.map(
+                                lambda x: jax.lax.all_gather(
+                                    x, rep, axis=0, tiled=True), tree)
+                        out = topo.aggregate(g, event, mask=mask)
+                        return jax.tree.map(
+                            lambda x: jax.lax.dynamic_index_in_dim(
+                                x, widx, axis=0, keepdims=True), out)
+                else:
+                    w = None if mask is None else mask.astype(acc)[widx]
+                    if wvec is not None:
+                        ws = jnp.asarray(wvec)[widx]
+                        w = ws if w is None else w * ws
+                    one = lambda x: topo.shard_aggregate(
+                        x, rep, event, worker_index=widx, weight=w)
+
+                    def reduce_fn(tree):
+                        with jax.named_scope(COLLECTIVE):
+                            return jax.tree.map(one, tree)
+                new_p, new_o, new_c = _apply_sync(plan, reduce_fn, params,
+                                                  opt_state, cstate, wire=wire)
+                if plan.comms is not None:
+                    # same restores as SimExecutor._apply_event, per shard: the
+                    # comms path hands the reduce codec-roundtripped payloads,
+                    # so workers a partial-group event did not sync get their
+                    # true state back, and a masked-out worker's error-feedback
+                    # residual is not consumed
+                    if part is not None:
+                        keep = jnp.asarray(part)[widx]
+                        new_p = _keep_shard(keep, new_p, params)
+                        new_o = _keep_shard(keep, new_o, opt_state)
+                        if cstate is not None:
+                            new_c = _keep_shard(keep, new_c, cstate)
+                    if mask is not None and cstate is not None:
+                        new_c = _keep_shard(mask.astype(bool)[widx], new_c,
+                                            cstate)
+                if drop:
+                    keep = mask.astype(bool)[widx]
                     new_p = _keep_shard(keep, new_p, params)
                     new_o = _keep_shard(keep, new_o, opt_state)
                     if cstate is not None:
                         new_c = _keep_shard(keep, new_c, cstate)
-                if mask is not None and cstate is not None:
-                    new_c = _keep_shard(mask.astype(bool)[widx], new_c,
-                                        cstate)
-            if drop:
-                keep = mask.astype(bool)[widx]
-                new_p = _keep_shard(keep, new_p, params)
-                new_o = _keep_shard(keep, new_o, opt_state)
-                if cstate is not None:
-                    new_c = _keep_shard(keep, new_c, cstate)
-            return new_p, new_o, new_c
+                return new_p, new_o, new_c
 
         return apply_event
 
@@ -659,47 +673,49 @@ class MeshExecutor(Executor):
                     for op in ops if op.snapshot}
 
         def fold(params, opt_state, pending, mask, widx, want_staleness):
-            pre_params = params
-            pending = dict(pending)
-            for op in ops:
-                slot = pending[op.level]
-                snaps, res = list(slot.snaps), slot.residual
-                s = len(snaps)
-                for j in range(op.n_fold):
-                    snap = snaps[s - op.warm + j]
-                    params = _stale_delta(params, snap.agg, snap.params)
-                    if agg_opt:
-                        opt_state = _merge_moments(
-                            opt_state, _stale_delta(
-                                _moments_only(opt_state),
-                                snap.agg_opt, snap.opt))
-                if op.snapshot:
-                    base_o = _moments_only(opt_state) if agg_opt else {}
-                    new_p, new_o, res = appliers[op.level](
-                        params, base_o, res, mask, widx)
-                    snaps = snaps[1:] + [StaleSnap(
-                        params, base_o, new_p,
-                        _moments_only(new_o) if agg_opt else {})]
-                pending[op.level] = StaleSlot(tuple(snaps), res)
-            stale_val = None
-            if want_staleness:
-                own = ops[-1] if ops and ops[-1].snapshot else None
-                if own is not None and own.n_fold:
-                    w = None if mask is None else mask.astype(acc)[widx]
-                    if wvec is not None:
-                        ws = jnp.asarray(wvec)[widx]
-                        w = ws if w is None else w * ws
-                    fresh = jax.tree.map(
-                        lambda x: topo.shard_aggregate(
-                            x, rep, event, worker_index=widx, weight=w),
-                        pre_params)
-                    d2 = sum(jnp.sum(jnp.square((a - f).astype(jnp.float32)))
-                             for a, f in zip(jax.tree.leaves(params),
-                                             jax.tree.leaves(fresh)))
-                    stale_val = jax.lax.pmean(d2, rep)
-                else:
-                    stale_val = jnp.zeros((), jnp.float32)
-            return params, opt_state, pending, stale_val
+            with jax.named_scope(SYNC_FOLD):
+                pre_params = params
+                pending = dict(pending)
+                for op in ops:
+                    slot = pending[op.level]
+                    snaps, res = list(slot.snaps), slot.residual
+                    s = len(snaps)
+                    for j in range(op.n_fold):
+                        snap = snaps[s - op.warm + j]
+                        params = _stale_delta(params, snap.agg, snap.params)
+                        if agg_opt:
+                            opt_state = _merge_moments(
+                                opt_state, _stale_delta(
+                                    _moments_only(opt_state),
+                                    snap.agg_opt, snap.opt))
+                    if op.snapshot:
+                        base_o = _moments_only(opt_state) if agg_opt else {}
+                        new_p, new_o, res = appliers[op.level](
+                            params, base_o, res, mask, widx)
+                        snaps = snaps[1:] + [StaleSnap(
+                            params, base_o, new_p,
+                            _moments_only(new_o) if agg_opt else {})]
+                    pending[op.level] = StaleSlot(tuple(snaps), res)
+                stale_val = None
+                if want_staleness:
+                    own = ops[-1] if ops and ops[-1].snapshot else None
+                    if own is not None and own.n_fold:
+                        w = None if mask is None else mask.astype(acc)[widx]
+                        if wvec is not None:
+                            ws = jnp.asarray(wvec)[widx]
+                            w = ws if w is None else w * ws
+                        fresh = jax.tree.map(
+                            lambda x: topo.shard_aggregate(
+                                x, rep, event, worker_index=widx, weight=w),
+                            pre_params)
+                        d2 = sum(jnp.sum(jnp.square(
+                                     (a - f).astype(jnp.float32)))
+                                 for a, f in zip(jax.tree.leaves(params),
+                                                 jax.tree.leaves(fresh)))
+                        stale_val = jax.lax.pmean(d2, rep)
+                    else:
+                        stale_val = jnp.zeros((), jnp.float32)
+                return params, opt_state, pending, stale_val
 
         return fold
 
@@ -789,8 +805,9 @@ class MeshExecutor(Executor):
                 return (p, o), jax.tree.map(lambda m: m.mean(), metrics)
 
             (p0, o0) = params, opt_state
-            (params, opt_state), metrics = jax.lax.scan(
-                local_block, (params, opt_state), stacked)
+            with jax.named_scope(LOCAL):
+                (params, opt_state), metrics = jax.lax.scan(
+                    local_block, (params, opt_state), stacked)
             widx = flat_worker_index(rep, sizes)
             if masked and not drop:
                 # Algorithm-1 masked step: a non-participating worker never

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.topology import SyncEvent, Topology
+from repro.obs.spans import LOCAL_FWD, LOCAL_UPDATE
 from repro.optim.optimizers import Optimizer
 
 
@@ -449,7 +450,12 @@ class HSGD:
         worker — the pure per-worker half of the plan (with gradient
         accumulation folded in); executors map it over the worker axis
         (vmap under sim, one worker per mesh replica under mesh)."""
-        grad_fn = jax.grad(lambda p, b: self.loss_fn(p, b), has_aux=True)
+        def loss(params, batch):
+            # the backward pass carries this scope under ``transpose(...)``
+            with jax.named_scope(LOCAL_FWD):
+                return self.loss_fn(params, batch)
+
+        grad_fn = jax.grad(loss, has_aux=True)
         accum = self.accum_steps
 
         def mean_grads(params, batch):
@@ -482,8 +488,10 @@ class HSGD:
                 metrics["grad_norm"] = jnp.sqrt(sum(
                     jnp.sum(jnp.square(g.astype(jnp.float32)))
                     for g in jax.tree.leaves(grads)))
-            updates, opt_state = self.optimizer.update(grads, opt_state, params)
-            params = jax.tree.map(jnp.add, params, updates)
+            with jax.named_scope(LOCAL_UPDATE):
+                updates, opt_state = self.optimizer.update(grads, opt_state,
+                                                           params)
+                params = jax.tree.map(jnp.add, params, updates)
             return params, opt_state, metrics
 
         return local_update
@@ -571,20 +579,34 @@ class HSGD:
         own (topology static masks + the elastic clock): each executed
         sync consults ``round_mask`` once, and a non-None mask routes the
         round through the masked executor variant — this is how the
-        population engine masks a draw's empty slots out of every sync."""
+        population engine masks a draw's empty slots out of every sync.
+
+        On the profiler's clock the call is the host span
+        ``hsgd.run_rounds``, with ``hsgd.schedule``, one ``hsgd.dispatch``
+        per round, ``hsgd.drain`` and ``hsgd.history`` inside it
+        (:mod:`repro.obs.spans`)."""
+        from repro.obs import span
         t0 = int(state.step)
+        with span("hsgd.run_rounds", call=t0):
+            return self._run_rounds(state, batch_fn, T, t0, eval_every,
+                                    eval_fn, trace, participation)
+
+    def _run_rounds(self, state: HSGDState, batch_fn, T: int, t0: int,
+                    eval_every: int, eval_fn, trace, participation):
+        from repro.obs import span
         cut = eval_every if (eval_fn is not None and eval_every) else 0
-        full = self.topology.schedule(t0 + T)
-        schedule = full[t0:]
-        warm0 = async_warmup(full[:t0], self.async_levels) \
-            if (self.async_levels and t0) else None
-        rounds = compile_schedule(schedule, cut_every=cut, t0=t0,
-                                  async_levels=self.async_levels or None,
-                                  warm0=warm0)
-        wire = None
-        if self.comms is not None:
-            ws = self.wire_stats(state)
-            wire = [ws.bytes_for_event(ev) for ev in schedule]
+        with span("hsgd.schedule", call=t0):
+            full = self.topology.schedule(t0 + T)
+            schedule = full[t0:]
+            warm0 = async_warmup(full[:t0], self.async_levels) \
+                if (self.async_levels and t0) else None
+            rounds = compile_schedule(schedule, cut_every=cut, t0=t0,
+                                      async_levels=self.async_levels or None,
+                                      warm0=warm0)
+            wire = None
+            if self.comms is not None:
+                ws = self.wire_stats(state)
+                wire = [ws.bytes_for_event(ev) for ev in schedule]
         clock = None
         sim: List[Tuple[float, Dict[str, float]]] = []  # per-step snapshots
         if self.runtime is not None:
@@ -612,51 +634,55 @@ class HSGD:
             # drain; rows get their (step, level) back from the schedule
             if not pending:
                 return st
-            mb = jax.device_get(st.metrics)
-            k = int(mb.count)
-            assert k == len(pending) <= cap, (k, len(pending), cap)
-            for (step_no, lvl), row in zip(pending, mb.rows[:k]):
-                vals = {key: float(v) for key, v in zip(div_keys, row)}
-                probe_vals[step_no] = vals
-                if trace is not None:
-                    trace.divergences(step_no, lvl, ts_of(step_no), vals)
-            pending.clear()
-            return dataclasses.replace(st, metrics=st.metrics.reset())
+            with span("hsgd.drain", call=t0):
+                mb = jax.device_get(st.metrics)
+                k = int(mb.count)
+                assert k == len(pending) <= cap, (k, len(pending), cap)
+                for (step_no, lvl), row in zip(pending, mb.rows[:k]):
+                    vals = {key: float(v) for key, v in zip(div_keys, row)}
+                    probe_vals[step_no] = vals
+                    if trace is not None:
+                        trace.divergences(step_no, lvl, ts_of(step_no), vals)
+                pending.clear()
+                return dataclasses.replace(st, metrics=st.metrics.reset())
 
         raw: List[Tuple[int, int, Dict]] = []  # (t_end, n_local, metrics)
         evals: Dict[int, Dict] = {}
         t = t0
         for rnd in rounds:
-            batches = tuple(batch_fn(t + i) for i in range(rnd.n_local))
-            mask = None
-            if clock is not None:
-                for i in range(rnd.n_local):
-                    clock.advance(t + i)
-                    sim.append((clock.time_s, clock.level_seconds()))
-                if rnd.event is not None:
+            level = rnd.event.level if rnd.event is not None else 0
+            with span("hsgd.dispatch", call=t0, n_local=rnd.n_local,
+                      level=level):
+                batches = tuple(batch_fn(t + i) for i in range(rnd.n_local))
+                mask = None
+                if clock is not None:
+                    for i in range(rnd.n_local):
+                        clock.advance(t + i)
+                        sim.append((clock.time_s, clock.level_seconds()))
+                    if rnd.event is not None:
+                        mask = parts.round_mask(rnd.event)
+                        # the sync belongs to the round's last step
+                        sim[-1] = (clock.time_s, clock.level_seconds())
+                elif parts is not None and rnd.event is not None:
                     mask = parts.round_mask(rnd.event)
-                    # the sync belongs to the round's last step
-                    sim[-1] = (clock.time_s, clock.level_seconds())
-            elif parts is not None and rnd.event is not None:
-                mask = parts.round_mask(rnd.event)
-            if clock is None and trace is not None:
-                # no runtime: keep the trace well-formed in step-index time
-                trace.name_process(0, "engine")
-                trace.name_thread(0, 0, "rounds (step-index time)")
-                trace.complete(f"round x{rnd.n_local}", float(t),
-                               float(rnd.n_local), pid=0, tid=0)
-                if rnd.event is not None:
-                    trace.sync_span(
-                        rnd.event.level, float(t + rnd.n_local), 0.0,
-                        payload_bytes=wire[t + rnd.n_local - t0 - 1]
-                        if wire is not None else 0)
-            if probes and rnd.event is not None and len(pending) >= cap:
-                state = drain(state)   # never let the ring wrap
-            if mask is None:
-                state, metrics = self.round_fn(rnd)(state, batches)
-            else:
-                state, metrics = self.round_fn(rnd, masked=True)(
-                    state, batches, jnp.asarray(mask))
+                if clock is None and trace is not None:
+                    # no runtime: keep the trace well-formed in step-index time
+                    trace.name_process(0, "engine")
+                    trace.name_thread(0, 0, "rounds (step-index time)")
+                    trace.complete(f"round x{rnd.n_local}", float(t),
+                                   float(rnd.n_local), pid=0, tid=0)
+                    if rnd.event is not None:
+                        trace.sync_span(
+                            rnd.event.level, float(t + rnd.n_local), 0.0,
+                            payload_bytes=wire[t + rnd.n_local - t0 - 1]
+                            if wire is not None else 0)
+                if probes and rnd.event is not None and len(pending) >= cap:
+                    state = drain(state)   # never let the ring wrap
+                if mask is None:
+                    state, metrics = self.round_fn(rnd)(state, batches)
+                else:
+                    state, metrics = self.round_fn(rnd, masked=True)(
+                        state, batches, jnp.asarray(mask))
             t += rnd.n_local
             raw.append((t, rnd.n_local, metrics))
             if rnd.event is not None:
@@ -673,32 +699,33 @@ class HSGD:
             state = drain(state)
         # metrics stay on device until here so rounds dispatch back-to-back;
         # one bulk transfer at the end instead of a sync per step
-        history: List[Dict] = []
-        for t_end, n_local, metrics in raw:
-            metrics = jax.device_get(metrics)
-            for i in range(n_local):
-                step_no = t_end - n_local + i + 1
-                rec = {"t": step_no,
-                       **{k: float(v[i]) for k, v in metrics.items()}}
-                if wire is not None:
-                    rec["wire_bytes"] = wire[step_no - t0 - 1]
-                if clock is not None:
-                    time_s, sync_s = sim[step_no - t0 - 1]
-                    rec["sim_time_s"] = round(time_s, 6)
-                    rec["sim_sync_s"] = sync_s
-                    if step_no in drops:
-                        rec["dropped"] = drops[step_no]
-                rec.update(probe_vals.get(step_no, {}))
-                rec.update(evals.get(step_no, {}))
-                history.append(rec)
-        if self.metrics is not None:
-            from repro.obs import validate_record
-            for rec in history:
-                errs = validate_record(rec)
-                if errs:
-                    raise ValueError(
-                        "metrics-bus violations in run_rounds history at "
-                        f"t={rec.get('t')}: " + "; ".join(errs))
+        with span("hsgd.history", call=t0):
+            history: List[Dict] = []
+            for t_end, n_local, metrics in raw:
+                metrics = jax.device_get(metrics)
+                for i in range(n_local):
+                    step_no = t_end - n_local + i + 1
+                    rec = {"t": step_no,
+                           **{k: float(v[i]) for k, v in metrics.items()}}
+                    if wire is not None:
+                        rec["wire_bytes"] = wire[step_no - t0 - 1]
+                    if clock is not None:
+                        time_s, sync_s = sim[step_no - t0 - 1]
+                        rec["sim_time_s"] = round(time_s, 6)
+                        rec["sim_sync_s"] = sync_s
+                        if step_no in drops:
+                            rec["dropped"] = drops[step_no]
+                    rec.update(probe_vals.get(step_no, {}))
+                    rec.update(evals.get(step_no, {}))
+                    history.append(rec)
+            if self.metrics is not None:
+                from repro.obs import validate_record
+                for rec in history:
+                    errs = validate_record(rec)
+                    if errs:
+                        raise ValueError(
+                            "metrics-bus violations in run_rounds history at "
+                            f"t={rec.get('t')}: " + "; ".join(errs))
         return state, history
 
     def drain_metrics(self, state: HSGDState

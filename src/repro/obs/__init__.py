@@ -14,10 +14,14 @@ Three parts (DESIGN.md §7 is the full contract):
 * **trace** (:mod:`.trace`) — :class:`TraceRecorder` exports the run as
   Chrome-trace-event/Perfetto JSON (``python -m repro.obs`` is the CLI;
   ``run_rounds(..., trace=recorder)`` the engine hook).
+* **spans** (:mod:`.spans`) — the names the profiler's trace of a real run
+  carries: ``run_rounds``'s host spans (:func:`span`) and the device
+  scopes of the round programs.
 """
 from repro.obs.bus import (SCHEMA_VERSION, MetricSpec, register_metric,
                            registered_metrics, spec_for, validate_record)
 from repro.obs.probes import MetricBuffer, Metrics, MetricsLike, make_metrics
+from repro.obs.spans import SPANS, span
 from repro.obs.trace import TraceRecorder, validate_trace
 
 __all__ = [
@@ -25,4 +29,5 @@ __all__ = [
     "MetricSpec", "SCHEMA_VERSION", "register_metric", "registered_metrics",
     "spec_for", "validate_record",
     "TraceRecorder", "validate_trace",
+    "SPANS", "span",
 ]

@@ -15,7 +15,7 @@ key still meant what a consumer expected.  The bus is that check:
   so user ``eval_fn`` extras pass through; ``launch/train.py`` and the
   benchmarks validate their own fully-registered records strictly);
 * ``SCHEMA_VERSION`` stamps exported artifacts (train JSONL header, trace
-  metadata, BENCH_obs.json) so downstream tooling can detect shape changes.
+  metadata) so downstream tooling can detect shape changes.
 
 Every channel the engine emits today is pre-registered below; new
 subsystems register theirs at import time (the registry is additive —

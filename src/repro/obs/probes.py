@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.divergence import partition_divergences_tree
+from repro.obs.spans import PROBE
 
 
 @jax.tree_util.register_dataclass
@@ -66,10 +67,12 @@ class MetricBuffer:
     def push(self, row: jax.Array) -> "MetricBuffer":
         """Append one probe row (jit-safe; wraps at capacity — the engine
         drains before that ever happens)."""
-        idx = self.count % self.rows.shape[0]
-        row = jnp.reshape(row, (-1,)).astype(self.rows.dtype)
-        rows = jax.lax.dynamic_update_index_in_dim(self.rows, row, idx, 0)
-        return MetricBuffer(rows, self.count + 1)
+        with jax.named_scope(PROBE):
+            idx = self.count % self.rows.shape[0]
+            row = jnp.reshape(row, (-1,)).astype(self.rows.dtype)
+            rows = jax.lax.dynamic_update_index_in_dim(self.rows, row, idx,
+                                                       0)
+            return MetricBuffer(rows, self.count + 1)
 
     def reset(self) -> "MetricBuffer":
         """Post-drain buffer: same storage, count back to zero (rows are
@@ -143,6 +146,7 @@ class Metrics:
         groupings = topology.level_groupings()
         ordered = [groupings[lvl] for lvl in self.levels(topology)]
 
+        @jax.named_scope(PROBE)
         def row(params) -> jax.Array:
             return partition_divergences_tree(params, ordered)
 
@@ -165,6 +169,7 @@ class Metrics:
         levels = self.levels(topology)
         assert len(rep_axes) == len(levels) + 1, (rep_axes, levels)
 
+        @jax.named_scope(PROBE)
         def row(params) -> jax.Array:
             # this shard's whole replica as one flat f32 vector
             x = jnp.concatenate(

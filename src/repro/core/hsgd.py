@@ -445,25 +445,50 @@ class HSGD:
         return pending
 
     # -- building blocks ------------------------------------------------------
+    @property
+    def update_path(self) -> str:
+        """``"seeded"`` where the optimizer's update is a scalar times the
+        gradient (:attr:`~repro.optim.Optimizer.seed`), so the local step
+        pulls that scalar back through the loss and gets the update itself;
+        ``"grad"`` where it forms the gradient and calls ``update``."""
+        return "seeded" if self.optimizer.seed is not None else "grad"
+
     def local_update_fn(self):
         """(params, opt_state, batch) -> (params, opt_state, metrics) for ONE
         worker — the pure per-worker half of the plan (with gradient
         accumulation folded in); executors map it over the worker axis
-        (vmap under sim, one worker per mesh replica under mesh)."""
+        (vmap under sim, one worker per mesh replica under mesh).
+
+        On the seeded path (:attr:`update_path`) the backward is seeded
+        with the optimizer's scale instead of 1, so it returns the update
+        and ``local/update`` holds only the add; the ``grad_norm`` channel
+        reads ‖update‖ / |scale| (not finite at a zero step size)."""
         def loss(params, batch):
             # the backward pass carries this scope under ``transpose(...)``
             with jax.named_scope(LOCAL_FWD):
                 return self.loss_fn(params, batch)
 
-        grad_fn = jax.grad(loss, has_aux=True)
+        def pullback(params, batch, scale):
+            """(scale · ∇loss, aux); the plain gradient for scale None."""
+            if scale is None:
+                return jax.grad(loss, has_aux=True)(params, batch)
+            out, vjp, metrics = jax.vjp(lambda p: loss(p, batch), params,
+                                        has_aux=True)
+            # ones_like keeps the loss's type (its mesh axes under shard_map)
+            (upd,) = vjp(jnp.ones_like(out) * scale.astype(out.dtype))
+            return upd, metrics
+
         accum = self.accum_steps
 
-        def mean_grads(params, batch):
+        def accumulate(params, batch, scale):
+            """The microbatch mean of ``pullback``: one large-batch step."""
             if accum == 1:
-                return grad_fn(params, batch)
+                return pullback(params, batch, scale)
+            # seeded: the 1/accum rides on the seed, the sum is the mean
+            micro_scale = None if scale is None else scale / accum
 
             def micro(acc, mb):
-                g, m = grad_fn(params, mb)
+                g, m = pullback(params, mb, micro_scale)
                 return jax.tree.map(
                     lambda a, gi: a + gi.astype(jnp.float32), acc, g), m
 
@@ -473,24 +498,33 @@ class HSGD:
             zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                 params)
             gsum, ms = jax.lax.scan(micro, zero, mbs)
-            grads = jax.tree.map(
-                lambda g, p: (g / accum).astype(p.dtype), gsum, params)
-            return grads, jax.tree.map(lambda m: m.mean(0), ms)
+            if scale is None:
+                gsum = jax.tree.map(lambda g: g / accum, gsum)
+            out = jax.tree.map(lambda g, p: g.astype(p.dtype), gsum, params)
+            return out, jax.tree.map(lambda m: m.mean(0), ms)
 
         grad_norm = self.metrics is not None and self.metrics.grad_norm
+        seed = self.optimizer.seed
 
         def local_update(params, opt_state, batch):
-            grads, metrics = mean_grads(params, batch)
+            scale = None
+            if seed is not None:
+                scale, opt_state = seed(opt_state)
+            pulled, metrics = accumulate(params, batch, scale)
             if grad_norm:
                 # per-worker gradient l2 norm; executors mean it over the
                 # worker axis like every other per-step metric channel
                 metrics = dict(metrics)
-                metrics["grad_norm"] = jnp.sqrt(sum(
+                norm = jnp.sqrt(sum(
                     jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)))
+                    for g in jax.tree.leaves(pulled)))
+                metrics["grad_norm"] = norm if scale is None \
+                    else norm / jnp.abs(scale)
             with jax.named_scope(LOCAL_UPDATE):
-                updates, opt_state = self.optimizer.update(grads, opt_state,
-                                                           params)
+                updates = pulled
+                if scale is None:
+                    updates, opt_state = self.optimizer.update(
+                        pulled, opt_state, params)
                 params = jax.tree.map(jnp.add, params, updates)
             return params, opt_state, metrics
 
@@ -582,12 +616,13 @@ class HSGD:
         population engine masks a draw's empty slots out of every sync.
 
         On the profiler's clock the call is the host span
-        ``hsgd.run_rounds``, with ``hsgd.schedule``, one ``hsgd.dispatch``
-        per round, ``hsgd.drain`` and ``hsgd.history`` inside it
+        ``hsgd.run_rounds`` (argument ``update``, the :attr:`update_path`),
+        with ``hsgd.schedule``, one ``hsgd.dispatch`` per round,
+        ``hsgd.drain`` and ``hsgd.history`` inside it
         (:mod:`repro.obs.spans`)."""
         from repro.obs import span
         t0 = int(state.step)
-        with span("hsgd.run_rounds", call=t0):
+        with span("hsgd.run_rounds", call=t0, update=self.update_path):
             return self._run_rounds(state, batch_fn, T, t0, eval_every,
                                     eval_fn, trace, participation)
 

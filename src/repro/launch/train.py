@@ -349,9 +349,11 @@ def main(argv=None):
                                  population=population)
     eng = HSGD(model.loss, opt, topo, engine_config)
     from repro.obs import SCHEMA_VERSION
-    # JSONL header: the full engine configuration, round-trippable
+    # JSONL header: the full engine configuration, round-trippable, and
+    # the local step's update path (HSGD.update_path)
     print(json.dumps({"schema_version": SCHEMA_VERSION,
                       "backend": args.backend, "probes": args.probes,
+                      "update": eng.update_path,
                       "config": engine_config.describe()}))
 
     if population is not None:

@@ -3,7 +3,9 @@
 The host side of ``HSGD.run_rounds`` is marked with
 ``jax.profiler.TraceAnnotation`` spans, one name per phase of a call:
 
-* ``hsgd.run_rounds`` -- the whole call;
+* ``hsgd.run_rounds`` -- the whole call (argument ``update``: ``seeded``
+  where the local step pulls the optimizer's scale back through the loss,
+  ``grad`` where it forms the gradient; ``HSGD.update_path``);
 * ``hsgd.schedule`` -- ``topology.schedule``, ``compile_schedule`` and the
   static wire accounting;
 * ``hsgd.dispatch`` -- one per round: its batches from ``batch_fn`` and the
@@ -18,8 +20,10 @@ device side is named with ``jax.named_scope`` where the work is traced,
 under the names this module declares: ``local`` (the local block: a
 round's scan of local steps, which keeps the name on what XLA's loop
 passes rebuild), inside it ``local/fwd`` (the loss; its gradient carries
-the scope under ``transpose(...)``) and ``local/update`` (optimizer update
-and apply); ``sync/L<level>`` (one level's aggregation, with ``encode``,
+the scope under ``transpose(...)``) and ``local/update`` (the optimizer's
+update and its add; on the seeded path the add alone, the step size
+riding on the loss's cotangent under ``transpose(...)``);
+``sync/L<level>`` (one level's aggregation, with ``encode``,
 ``collective`` and ``decode`` inside where the comms path has them),
 ``sync/fold`` (the stale-sync fold) and ``probe`` (the divergence row and
 its push).  A scope costs nothing on the device (it is op metadata); a

@@ -6,7 +6,7 @@ schedule ``step -> float``; ``step`` is threaded through opt_state.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Union
+from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +20,19 @@ def _lr_at(lr: Schedule, step: jax.Array) -> jax.Array:
 
 @dataclasses.dataclass(frozen=True)
 class Optimizer:
+    """``update(grads, state, params) -> (updates, state)``.
+
+    ``seed(state) -> (scale, state)`` is set only where ``update`` is
+    exactly ``scale * grads`` with the same new state (plain SGD,
+    ``scale = -lr(step)``).  The engine then pulls ``scale`` back through
+    the loss (``jax.vjp`` with cotangent ``scale`` where ``jax.grad`` seeds
+    1), which yields the update itself: no gradient is formed and scaled in
+    a pass of its own.  An optimizer whose update is not linear in the
+    gradient (momentum, Adam) leaves it None and takes the gradient path.
+    """
     init: Callable[[Any], Any]
-    update: Callable[[Any, Any, Any], Any]  # (grads, state, params) -> (updates, state)
+    update: Callable[[Any, Any, Any], Any]
+    seed: Optional[Callable[[Any], Tuple[jax.Array, Any]]] = None
 
 
 def sgd(lr: Schedule) -> Optimizer:
@@ -35,7 +46,10 @@ def sgd(lr: Schedule) -> Optimizer:
         upd = jax.tree.map(lambda x: (-g * x.astype(jnp.float32)).astype(x.dtype), grads)
         return upd, {"step": state["step"] + 1}
 
-    return Optimizer(init, update)
+    def seed(state):
+        return -_lr_at(lr, state["step"]), {"step": state["step"] + 1}
+
+    return Optimizer(init, update, seed)
 
 
 def momentum(lr: Schedule, beta: float = 0.9, nesterov: bool = False) -> Optimizer:

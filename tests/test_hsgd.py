@@ -4,8 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (HSGD, GroupedTopology, HierarchySpec, UniformTopology,
-                        contiguous, local_sgd, two_level)
+from repro.core import (HSGD, EngineConfig, GroupedTopology, HierarchySpec,
+                        UniformTopology, contiguous, local_sgd, two_level)
 from repro.data import FederatedDataset, label_shard_partition, make_classification
 from repro.models import SimpleConfig, SimpleModel
 from repro.optim import adam, momentum, sgd
@@ -214,3 +214,122 @@ def test_grad_accumulation_equals_large_batch(setup):
         s1, m1 = e1.step(s1, b)
         s2, m2 = e2.step(s2, b)
     assert max_param_diff(s1.params, s2.params) < 1e-6
+
+
+# -- the seeded update: SGD's step size pulled back through the loss -------
+
+def _tied_lm():
+    """A 2-layer tied-embedding qwen2 at float32 compute."""
+    import dataclasses
+    from repro.configs import get_config, reduced
+    from repro.models import build_model
+    cfg = dataclasses.replace(reduced(get_config("qwen2-0.5b")),
+                              dtype="float32", param_dtype="float32")
+    assert cfg.tie_embeddings
+    return cfg, build_model(cfg)
+
+
+def _lm_batch(cfg, t, lead=()):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(100 + t))
+    shape = lead + (4, 8)
+    return {"tokens": jax.random.randint(k1, shape, 0, cfg.vocab_size),
+            "targets": jax.random.randint(k2, shape, 0, cfg.vocab_size)}
+
+
+def _hand_sgd_step(model, opt, params, state, batch, accum):
+    """params + opt.update(mean microbatch gradient), written out."""
+    mbs = jax.tree.map(lambda x: x.reshape((accum, -1) + x.shape[1:]), batch)
+    grads = [jax.grad(lambda p: model.loss(p, jax.tree.map(
+        lambda x: x[i], mbs))[0])(params) for i in range(accum)]
+    g = jax.tree.map(lambda *gs: sum(gs) / accum, *grads)
+    upd, state = opt.update(g, state, params)
+    norm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(g)))
+    return jax.tree.map(jnp.add, params, upd), state, norm
+
+
+def _rel(a, b):
+    num = sum(float(jnp.sum(jnp.square(x - y)))
+              for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+    den = sum(float(jnp.sum(jnp.square(y))) for y in jax.tree.leaves(b))
+    return (num / den) ** 0.5
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+@pytest.mark.parametrize("lr", ["constant", "warmup"])
+def test_seeded_update_equals_sgd_update_of_the_gradient(lr, accum):
+    """With SGD the local step pulls -lr back through the loss; two steps
+    of it equal params + sgd.update(grad) to float32 rounding, the
+    schedule's counter advances, and grad_norm reads the gradient's norm."""
+    from repro.optim import linear_warmup
+    cfg, model = _tied_lm()
+    opt = sgd(0.5 if lr == "constant" else linear_warmup(0.5, 4))
+    eng = HSGD(model.loss, opt, UniformTopology(two_level(4, 2, 4, 2)),
+               EngineConfig(metrics="on", accum_steps=accum))
+    assert eng.update_path == "seeded"
+    step = jax.jit(eng.local_update_fn())
+    p0 = model.init(jax.random.PRNGKey(0))
+    ps, ss, pr, sr = p0, opt.init(p0), p0, opt.init(p0)
+    for t in range(2):
+        batch = _lm_batch(cfg, t)
+        ps, ss, m = step(ps, ss, batch)
+        pr, sr, norm = _hand_sgd_step(model, opt, pr, sr, batch, accum)
+        assert int(ss["step"]) == int(sr["step"]) == t + 1
+        np.testing.assert_allclose(float(m["grad_norm"]), float(norm),
+                                   rtol=1e-5)
+    upd_s = jax.tree.map(jnp.subtract, ps, p0)
+    upd_r = jax.tree.map(jnp.subtract, pr, p0)
+    assert _rel(upd_s, upd_r) < 1e-5
+    assert _rel(ps, pr) < 1e-6
+
+
+@pytest.mark.parametrize("make", [lambda: momentum(0.1, beta=0.5),
+                                  lambda: adam(1e-2)],
+                         ids=["momentum", "adam"])
+def test_nonlinear_optimizers_keep_the_gradient_path(make):
+    """Momentum and Adam are not a scalar times the gradient: they report
+    update="grad" and their local step is, bitwise, jax.grad then
+    optimizer.update then the add."""
+    cfg, model = _tied_lm()
+    opt = make()
+    assert opt.seed is None
+    eng = HSGD(model.loss, opt, UniformTopology(two_level(4, 2, 4, 2)))
+    assert eng.update_path == "grad"
+
+    def by_hand(params, state, batch):
+        grads, metrics = jax.grad(model.loss, has_aux=True)(params, batch)
+        upd, state = opt.update(grads, state, params)
+        return jax.tree.map(jnp.add, params, upd), state, metrics
+
+    step, hand = jax.jit(eng.local_update_fn()), jax.jit(by_hand)
+    p0 = model.init(jax.random.PRNGKey(0))
+    pe, se, pr, sr = p0, opt.init(p0), p0, opt.init(p0)
+    for t in range(2):
+        pe, se, _ = step(pe, se, _lm_batch(cfg, t))
+        pr, sr, _ = hand(pr, sr, _lm_batch(cfg, t))
+    for a, b in zip(jax.tree.leaves((pe, se)), jax.tree.leaves((pr, sr))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_seeded_update_has_no_multiply_over_the_tied_embedding():
+    """The vmapped local step of a 2-layer tied qwen2, before XLA's passes:
+    the gradient path scales the embedding's [n, V, D] gradient by -lr in a
+    multiply of its own; the seeded path has none (the scale sits on the
+    loss's cotangent)."""
+    from repro.optim import Optimizer
+    cfg, model = _tied_lm()
+    n = 4
+    emb = f"f32[{n},{cfg.vocab_size},{cfg.d_model}]"
+
+    def multiplies(opt):
+        eng = HSGD(model.loss, opt, UniformTopology(two_level(n, 2, 4, 2)))
+        state = eng.init(jax.random.PRNGKey(0), model.init)
+        text = jax.jit(jax.vmap(eng.local_update_fn())).lower(
+            state.params, state.opt_state, _lm_batch(cfg, 0, (n,))
+        ).as_text(dialect="hlo")
+        return sum(1 for line in text.splitlines()
+                   if " multiply(" in line
+                   and line.split("=", 1)[1].lstrip().startswith(emb))
+
+    opt = sgd(3e-3)
+    assert multiplies(Optimizer(opt.init, opt.update)) == 1
+    assert multiplies(opt) == 0

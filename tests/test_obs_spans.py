@@ -119,11 +119,13 @@ def test_run_rounds_host_spans_in_order(monkeypatch, metrics):
     state, hist = eng.run_rounds(state, batch_fn, 4)
     drain = [("hsgd.drain", {"call": 4})] if metrics else []
     assert [(n, a) for kind, n, a in rec.log if kind == "enter"] == [
-        ("hsgd.run_rounds", {"call": 4}), ("hsgd.schedule", {"call": 4}),
+        ("hsgd.run_rounds", {"call": 4, "update": "seeded"}),
+        ("hsgd.schedule", {"call": 4}),
         ("hsgd.dispatch", {"call": 4, "n_local": 2, "level": 2}),
         ("hsgd.dispatch", {"call": 4, "n_local": 2, "level": 1}),
         *drain, ("hsgd.history", {"call": 4})]
-    assert rec.log[-1] == ("exit", "hsgd.run_rounds", {"call": 4})
+    assert rec.log[-1] == ("exit", "hsgd.run_rounds",
+                           {"call": 4, "update": "seeded"})
     # one transfer per round's metrics, one per drain: what the loop
     # did before it had spans
     assert len(gets) == 2 + len(drain)

@@ -47,14 +47,23 @@ def half_batch():
     return _patched(DecoderLM, "loss", half)
 
 
+@contextlib.contextmanager
 def sync_left_out():
-    """Every average (each level's exchange between workers) is skipped."""
-    from repro.core.executors import SimExecutor
+    """Every average (each level's exchange between workers: in the array
+    on the sim executor, between chips on the mesh) is skipped."""
+    from repro.core.executors import MeshExecutor, SimExecutor
 
     def apply_event(self, params, opt_state, cstate, event, mask=None,
                     drop=False):
         return params, opt_state, cstate
-    return _patched(SimExecutor, "_apply_event", apply_event)
+
+    def event_applier(self, event, drop=False):
+        return lambda params, opt_state, cstate, mask, widx: (
+            params, opt_state, cstate)
+
+    with _patched(SimExecutor, "_apply_event", apply_event), \
+            _patched(MeshExecutor, "_event_applier", event_applier):
+        yield
 
 
 def target_altered():

@@ -15,12 +15,16 @@ cache.  The window then calls ``run_rounds(state, batch_fn, T=G)`` back to
 back, each call finished once its history is on the host and
 ``block_until_ready(state)`` has returned, until ``seconds`` have passed.
 With ``trace`` one call under the profiler is left out and ``trace_calls``
-further calls make the traced segment.  After both, the program's state is
-freed and the plain reference replays the first ``check_steps`` steps.
+further calls make the traced segment; its trace is read once into the
+device's busy time (``trace_reduce``) and its split by the program's named
+scopes (``scopes``), both on the context the per-layer readers get.  After
+both, the program's state is freed and the plain reference replays the
+first ``check_steps`` steps.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import math
 import shutil
@@ -33,9 +37,9 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from chipbench import check, data, engine, flops, peaks, reference, weights
+from chipbench import check, data, engine, peaks, reference, scopes, weights
 from chipbench.registry import Cell, metric_reader
-from chipbench.trace_reduce import find_xplane, profile_events, reduce_events
+from chipbench.trace_reduce import find_xplane, reduce_events
 
 SPANS = ("chipbench.run_rounds", "chipbench.block", "chipbench.history")
 # the call that starts the profiler's segment: outside the traced window,
@@ -93,7 +97,7 @@ def prepare(cell: Cell, model_overrides: Optional[dict] = None) -> Prepared:
     tr = cell.traffic
     eng, program_model = engine.build(config, tr)
     m = config["model"]
-    make_params = weights.params_fn(m)
+    make_params = weights.params_fn(cell.family, m)
     want = jax.eval_shape(program_model.init, jax.random.PRNGKey(0))
     made = jax.eval_shape(make_params, jax.random.PRNGKey(0))
     if jax.tree.structure(want) != jax.tree.structure(made) or any(
@@ -167,13 +171,13 @@ def _batch_fn(pool):
 
 def reference_readings(prep: Prepared, seed: int, pool):
     """The reference's first ``check_steps`` steps from the same weights (in
-    float32) and the pool's first batches."""
+    float32) and the batches the program took from the pool."""
     k_w, _ = _keys(seed)
     p0 = jax.tree.map(lambda x: x.astype(np.float32), prep.make_params(k_w))
     tr = prep.cell.traffic
     return reference.first_global_round(
-        prep.model, tr["group_sizes"], tr["periods"], tr["lr"], p0,
-        list(pool[:prep.check_steps]))
+        prep.cell.family.loss, prep.model, tr["group_sizes"], tr["periods"],
+        tr["lr"], p0, [pool[t % len(pool)] for t in range(prep.check_steps)])
 
 
 def drive(eng, state, batch_fn, G: int, until: float, min_calls: int = 1,
@@ -218,6 +222,14 @@ class GcLog:
                 for g, p in self.pauses.items()}
 
 
+def read_trace(path: str):
+    """``(TraceSummary, ScopeSummary)`` of one ``.xplane.pb``, read once;
+    each None where the trace holds no device operation."""
+    events = scopes.read_events(path)
+    return (reduce_events(e[:5] for e in events),
+            scopes.reduce_scopes(events))
+
+
 def memory_peak(devices) -> int:
     stats = [d.memory_stats() or {} for d in devices]
     return int(max(s.get("peak_bytes_in_use", 0) for s in stats))
@@ -240,6 +252,12 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
     devices = chips_for(cell, require_tpu)
     if use_cache:
         enable_cache()
+    if trace:
+        # the scopes live in the programs' debug info, which JAX leaves out
+        # of the cache key unless told: without it a program cached by an
+        # untraced run, or before the scopes existed, comes back without them
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     clog = engine.CompileLog()
     stage("devices")
     prep = prepare(cell, model_overrides)
@@ -267,7 +285,7 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
                 "window_calls": len(lat), "window_s": window_s,
                 "call_s": [min(lat), statistics.median(lat), max(lat)],
                 "window_gc": gclog.close()}
-    summary, traced_calls = None, 0
+    summary, scope_summary, traced_calls = None, None, 0
     if trace:
         tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
         jax.profiler.start_trace(tdir)
@@ -279,9 +297,16 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
         jax.profiler.stop_trace()
         failed += failed_w + failed_t
         traced_calls = len(lat_t)
-        summary = reduce_events(profile_events(find_xplane(tdir)))
+        summary, scope_summary = read_trace(find_xplane(tdir))
         shutil.rmtree(tdir, ignore_errors=True)
         log_line["traced_call_s"] = lat_t
+        if scope_summary is not None:
+            steps = traced_calls * prep.G
+            log_line.update(
+                scope_ms_per_step={k: 1e3 * v / steps for k, v
+                                   in scope_summary.scope_s.items()},
+                host_gap_s=scope_summary.host_gap_s,
+                unscoped_ops=scope_summary.unscoped_ops)
     peak_bytes = memory_peak(devices)
     gc.unfreeze()
     del state
@@ -309,9 +334,14 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
         breakdown = None
     else:
         ctx = types.SimpleNamespace(
-            trace=summary, chips=cell.chips, device_kind=device["kind"],
+            trace=summary, scopes=scope_summary, chips=cell.chips,
+            device_kind=device["kind"],
             traced_tokens=traced_calls * prep.tokens_per_call,
-            flops_per_token=flops.flops_per_token(prep.model, tr["seq"]),
+            traced_steps=traced_calls * prep.G,
+            scope_seconds=functools.partial(scopes.under, scope_summary),
+            family=cell.family,
+            flops_per_token=cell.family.flops_per_token(prep.model,
+                                                        tr["seq"]),
             peaks=peaks, model=prep.model, traffic=tr)
         metrics, breakdown = {}, None
         for name, entry in cell.per_layer.items():

@@ -125,6 +125,7 @@ class ScopeSummary:
     host_gap_s: Dict[str, float]   # idle seconds by host span, device mean
     calls: int                     # ``hsgd.run_rounds`` spans in the window
     unscoped_ops: List[Tuple[str, float]]  # the largest, (op, seconds)
+    path_s: Dict[str, float]       # by ``tf_op`` path, device mean
 
 
 def reduce_scopes(events, top: int = 10) -> Optional[ScopeSummary]:
@@ -147,8 +148,8 @@ def reduce_scopes(events, top: int = 10) -> Optional[ScopeSummary]:
         t0 = min(s for evs in ops.values() for _, s, _, _ in evs)
         t1 = max(e for evs in ops.values() for _, _, e, _ in evs)
     n = len(ops)
-    scope_s, gap_s, unscoped = (defaultdict(float), defaultdict(float),
-                                defaultdict(float))
+    scope_s, gap_s, unscoped, path_s = (defaultdict(float), defaultdict(float),
+                                        defaultdict(float), defaultdict(float))
     busy = 0.0
     for evs in ops.values():
         merged = _clip(union((s, e) for _, s, e, _ in evs), t0, t1)
@@ -160,6 +161,7 @@ def reduce_scopes(events, top: int = 10) -> Optional[ScopeSummary]:
         for s, e, name, meta in leaves:
             scope = scope_of(meta or "")
             scope_s[scope] += (e - s) * 1e-9 / n
+            path_s[meta or ""] += (e - s) * 1e-9 / n
             if scope == UNSCOPED:
                 unscoped[f"{name} | {meta}"] += (e - s) * 1e-9 / n
         leaf_busy = sum(e - s for s, e in union((s, e)
@@ -175,7 +177,30 @@ def reduce_scopes(events, top: int = 10) -> Optional[ScopeSummary]:
         scope_s=dict(scope_s), host_gap_s=dict(gap_s),
         calls=sum(1 for name, s, e in host
                   if name == CALL_SPAN and s < t1 and e > t0),
-        unscoped_ops=sorted(unscoped.items(), key=lambda kv: -kv[1])[:top])
+        unscoped_ops=sorted(unscoped.items(), key=lambda kv: -kv[1])[:top],
+        path_s=dict(path_s))
+
+
+def under(summary: Optional[ScopeSummary],
+          name: str) -> Optional[Dict[str, float]]:
+    """Device seconds (mean over devices) of the operations whose ``tf_op``
+    path holds the named scope ``name`` (``jax.named_scope(name)`` anywhere
+    in the program, such as a layer's inside the model), split as
+    :func:`scope_of` splits the loss: ``fwd``, ``bwd`` (under
+    ``transpose(...)``) and ``remat`` (what ``jax.checkpoint`` recomputes
+    there).  None where nothing was recorded; zeros where no operation
+    holds the scope."""
+    if summary is None:
+        return None
+    held = re.compile(rf"(?:^|[/(]){re.escape(name)}(?:[/)]|$)")
+    out = {"fwd": 0.0, "bwd": 0.0, "remat": 0.0}
+    for tf_op, sec in summary.path_s.items():
+        path = tf_op.split(";", 1)[0].rstrip(":")
+        if held.search(path):
+            part = ("remat" if "rematted_computation" in path
+                    else "bwd" if "transpose(" in path else "fwd")
+            out[part] += sec
+    return out
 
 
 def host_span(spans, gs: float, ge: float) -> str:
@@ -221,6 +246,19 @@ def per_step(summary: ScopeSummary, steps: int) -> Dict[str, float]:
             v for k, v in summary.host_gap_s.items() if k != OUTSIDE)
         / max(summary.calls, 1),
     }
+
+
+def step_reading(ctx, key: str) -> Optional[float]:
+    """``per_step``'s ``key`` for a per-layer reader's context: None where
+    the run recorded no device trace, or a trace without the program's
+    scopes (a parent that names none, or one whose programs came from a
+    cache filled without them)."""
+    if ctx.scopes is None:
+        return None
+    try:
+        return per_step(ctx.scopes, ctx.traced_steps)[key]
+    except NoScopes:
+        return None
 
 
 # -- the file ---------------------------------------------------------------

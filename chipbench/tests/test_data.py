@@ -3,7 +3,8 @@ import jax
 import numpy as np
 
 from chipbench import data, weights
-from chipbench.tests.tiny import TINY
+from chipbench.registry import family
+from chipbench.tests.tiny import REPO, TINY
 
 
 def _pool(seed, steps=3, n=4):
@@ -36,7 +37,7 @@ def test_large_seeds_give_distinct_keys():
 
 def test_weights_repeat_and_follow_the_param_dtype():
     m = dict(TINY["tiny-dense"]["model"], param_dtype="bfloat16")
-    make = weights.params_fn(m)
+    make = weights.params_fn(family(REPO, "qwen2"), m)
     a, b = make(weights.seed_key(3)), make(weights.seed_key(3))
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         assert x.dtype == np.dtype("bfloat16")

@@ -8,7 +8,7 @@ from pathlib import Path
 import pytest
 
 from chipbench.scopes import (NoScopes, host_span, per_step, read_events,
-                              reduce_scopes, scope_of)
+                              reduce_scopes, scope_of, under)
 from chipbench.trace_reduce import profile_events, reduce_events
 
 DEV, OPS, HOST = "/device:TPU:0", "XLA Ops", "/host:CPU"
@@ -234,14 +234,74 @@ def test_recorded_scoped_trace():
                                  "hsgd.run_rounds", "outside hsgd"}
 
 
-def test_scope_report_runs_on_a_host_only_backend(tmp_path):
-    from chipbench import scope_report
-    from chipbench.tests.tiny import make_root
-    root = make_root(tmp_path)
-    r = scope_report.report(root, "tiny-dense.tiny", 2700000003,
-                            require_tpu=False)
-    assert r["scopes"] is None
-    assert (r["traced_calls"], r["traced_steps"], r["failed"]) == (2, 8, 0)
+# what the traced segment's split gave per step (``per_step`` at the
+# segment's steps) on ``testdata/scoped.xplane.pb``: two calls of G=4
+SCOPED_PER_STEP = {
+    "local.fwd_ms": 0.017087624999999978, "local.bwd_ms": 0.03071049999999997,
+    "local.update_ms": 0.0006657500000000002,
+    "local.other_ms": 0.004732999999999997, "sync.ms": 0.011693125,
+    "plan.host_gap_ms": 6.494713500000006}
+
+
+def _ctx(summary, scopes, steps):
+    import types
+    return types.SimpleNamespace(trace=summary, scopes=scopes,
+                                 traced_steps=steps)
+
+
+@pytest.mark.parametrize("name", sorted(SCOPED_PER_STEP))
+def test_step_reader_on_a_recorded_trace(name):
+    from chipbench.harness import read_trace
+    from chipbench.registry import metric_reader
+    from chipbench.tests.tiny import REPO
+    summary, s = read_trace(str(TESTDATA / "scoped.xplane.pb"))
+    assert metric_reader(REPO, name)(_ctx(summary, s, 8)) == \
+        SCOPED_PER_STEP[name]
+
+
+@pytest.mark.parametrize("name", sorted(SCOPED_PER_STEP))
+def test_step_reader_has_nothing_to_read_without_scopes(name):
+    from chipbench.registry import metric_reader
+    from chipbench.tests.tiny import REPO
+    read = metric_reader(REPO, name)
+    # a host-only backend records no device operation
+    assert read(_ctx(None, None, 8)) is None
+    # a program without scopes, or one from a cache filled without them
+    evs = [e[:5] + ("",) if e[0] == DEV else e for e in events()]
+    assert read(_ctx(None, reduce_scopes(evs), 5)) is None
+
+
+def test_the_scopes_add_up_to_the_busy_time_per_step():
+    from chipbench.harness import read_trace
+    summary, s = read_trace(str(TESTDATA / "scoped.xplane.pb"))
+    m = per_step(s, 8)
+    rest = 1e3 * sum(v for k, v in s.scope_s.items()
+                     if k in ("unscoped", "control", "probe")) / 8
+    parts = sum(v for k, v in m.items() if k != "plan.host_gap_ms")
+    assert parts + rest == pytest.approx(1e3 * summary.busy_s / 8, rel=1e-9)
+
+
+def test_seconds_under_a_named_scope_inside_the_model():
+    # a scope of a later family's layer (``moe``) in the forward, the
+    # gradient and remat's recompute; ``moe_gate`` and ``premoe`` are not it
+    moe = BODY + "vmap(jvp(local/fwd))/while/body/moe/dot_general:"
+    evs = [
+        (DEV, OPS, "fusion.1", 0, 20, moe),
+        (DEV, OPS, "fusion.2", 20, 30, moe.replace("jvp(", "transpose(jvp(")),
+        (DEV, OPS, "fusion.3", 50, 5, REMAT.replace("/bcijh", "/moe/bcijh")),
+        (DEV, OPS, "fusion.4", 55, 7, moe.replace("/moe/", "/moe_gate/")),
+        (DEV, OPS, "fusion.5", 62, 3, moe.replace("/moe/", "/premoe/")),
+        (HOST, "python", "chipbench.run_rounds", 0, 100, {}),
+    ]
+    s = reduce_scopes(evs)
+    assert under(s, "moe") == pytest.approx(
+        {"fwd": 20e-9, "bwd": 30e-9, "remat": 5e-9})
+    assert under(s, "moe_gate") == pytest.approx(
+        {"fwd": 7e-9, "bwd": 0.0, "remat": 0.0})
+    assert under(s, "nothing") == {"fwd": 0.0, "bwd": 0.0, "remat": 0.0}
+    assert under(None, "moe") is None
+    # the program's own scopes read the same way
+    assert under(s, "local/fwd")["bwd"] == pytest.approx(30e-9)
 
 
 def test_the_reader_knows_the_programs_span_names():

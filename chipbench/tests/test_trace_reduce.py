@@ -82,3 +82,16 @@ def test_control_flow_counts_as_busy_but_not_among_the_top_ops():
     s = reduce_events(events)
     assert s.busy_s == pytest.approx(30e-9)
     assert [name for name, _ in s.top_ops] == ["fusion.1", "fusion.2"]
+
+
+def test_collective_time_counts_inside_the_window():
+    # an all-reduce that starts before the window's first harness span and
+    # one that runs past its end count only for their part inside it
+    events = [
+        (DEV, OPS, "all-reduce.1", 0, 10), (DEV, OPS, "fusion.1", 10, 10),
+        (DEV, OPS, "all-reduce-start.2", 20, 10),
+        ("/host:CPU", "python", "chipbench.run_rounds", 5, 20),
+    ]
+    s = reduce_events(events)
+    assert s.collective_s == {DEV: pytest.approx(10e-9)}
+    assert s.collective_s[DEV] <= s.busy_s_per_device[DEV]

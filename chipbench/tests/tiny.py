@@ -7,7 +7,7 @@ REPO = Path(__file__).resolve().parents[2]
 
 TINY = {
     "tiny-dense": {
-        "registry": "qwen2-0.5b",
+        "registry": "qwen2-0.5b", "family": "qwen2",
         "model": {"num_layers": 2, "d_model": 64, "num_heads": 4,
                   "num_kv_heads": 2, "head_dim": 16, "d_ff": 128,
                   "vocab_size": 256, "qkv_bias": True,
@@ -17,7 +17,7 @@ TINY = {
                   "dtype": "float32", "param_dtype": "float32",
                   "remat": False}},
     "tiny-ssd": {
-        "registry": "mamba2-130m",
+        "registry": "mamba2-130m", "family": "mamba2",
         "model": {"num_layers": 2, "d_model": 32, "num_heads": 4,
                   "num_kv_heads": 4, "d_ff": 0, "vocab_size": 128,
                   "mlp_variant": "none", "block_pattern": ["ssd"],

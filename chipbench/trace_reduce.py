@@ -15,8 +15,9 @@ the device's operations.  The reduction works on flat event records
   is left out of them;
 * each idle gap is named after the harness span that overlaps it most,
   which says what the host was doing while the device waited;
-* collective time per device is the summed duration of its all-reduce,
-  all-gather, reduce-scatter, all-to-all and collective-permute operations.
+* collective time per device is the summed duration, inside the window, of
+  its all-reduce, all-gather, reduce-scatter, all-to-all and
+  collective-permute operations.
 """
 from __future__ import annotations
 
@@ -104,7 +105,7 @@ def reduce_events(events: Iterable[Event],
                 if not name.startswith(CONTROL_FLOW):
                     per_op[name] += (min(e, t1) - max(s, t0)) * 1e-9 / len(ops)
                 if any(c in name for c in COLLECTIVES):
-                    coll[plane] += (e - s) * 1e-9
+                    coll[plane] += (min(e, t1) - max(s, t0)) * 1e-9
     gaps.sort(key=lambda g: -g[1])
     return TraceSummary(
         window_s=(t1 - t0) * 1e-9,

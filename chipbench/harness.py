@@ -133,20 +133,38 @@ def _like(tree, state_params):
     return jax.device_put(tree, NamedSharding(sharding.mesh, P()))
 
 
-def first_round(prep: Prepared, seed: int, finish: bool = True):
+class Laps:
+    """Seconds of named stages on ``time.perf_counter``'s clock: each call
+    adds the time since the last one (or ``t0``) to its stage."""
+
+    def __init__(self, t0: float):
+        self.mark, self.seconds = t0, {}
+
+    def __call__(self, name: str):
+        now = time.perf_counter()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - self.mark
+        self.mark = now
+
+
+def first_round(prep: Prepared, seed: int, finish: bool = True,
+                lap: Optional[Laps] = None):
     """Build the state from ``seed`` and drive it through the first global
     round: one call up to each average within ``check_steps``, then (with
     ``finish``) one call for the rest.  Returns ``(state, pool, losses,
     readings)``, ``losses`` of the first ``check_steps`` steps and
-    ``readings[step] = (delta, spread)`` after each of their averages."""
+    ``readings[step] = (delta, spread)`` after each of their averages.
+    ``lap`` times the stages ``init``, ``round_calls`` and ``readings``."""
+    lap = lap or Laps(time.perf_counter())
     k_w, k_d = _keys(seed)
     p0 = prep.make_params(k_w)
     pool = prep.make_pool(k_d)
     eng, tr = prep.eng, prep.cell.traffic
     state = eng.init(k_w, lambda _: p0)
     p0 = _like(p0, state.params)
+    jax.block_until_ready((state, p0))
     batch_fn = _batch_fn(pool)
     losses, readings, t = [], {}, 0
+    lap("init")
     for step in range(prep.check_steps):
         level = reference.sync_level(step, tr["periods"])
         if not level:
@@ -154,14 +172,16 @@ def first_round(prep: Prepared, seed: int, finish: bool = True):
         state, hist = eng.run_rounds(state, batch_fn, step + 1 - t)
         jax.block_until_ready(state)
         losses += [r["ce"] for r in hist]
+        lap("round_calls")
         leaders = reference.group_leaders(prep.n, tr["group_sizes"], level)
         readings[step + 1] = tuple(np.asarray(a) for a in jax.device_get(
-            check.program_readings(_like(state.params, state.params), p0,
-                                   leaders)))
+            check.program_readings(state.params, p0, leaders)))
+        lap("readings")
         t = step + 1
     if finish and t < prep.G:
         state, _ = eng.run_rounds(state, batch_fn, prep.G - t)
         jax.block_until_ready(state)
+        lap("round_calls")
     return state, pool, losses, readings
 
 
@@ -241,14 +261,8 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
              log: Callable[[str], None] = print) -> dict:
     """One run; returns the result object ``run.py`` prints.  ``t_start``
     is the process's start on ``time.perf_counter``'s clock."""
-    stages, mark = {"start": time.perf_counter() - t_start}, time.perf_counter()
-
-    def stage(name):
-        nonlocal mark
-        now = time.perf_counter()
-        stages[name] = now - mark
-        mark = now
-
+    stage = Laps(t_start)
+    stage("start")
     devices = chips_for(cell, require_tpu)
     if use_cache:
         enable_cache()
@@ -262,13 +276,14 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
     stage("devices")
     prep = prepare(cell, model_overrides)
     stage("prepare")
-    state, pool, prog_losses, prog_readings = first_round(prep, seed)
+    state, pool, prog_losses, prog_readings = first_round(prep, seed,
+                                                         lap=stage)
     batch_fn = _batch_fn(pool)
     # what set-up leaves behind is never garbage: keep it out of the
     # collections that the window's calls set off
     gc.collect()
     gc.freeze()
-    stage("first_round")
+    stage("gc")
     setup_compiles = clog.snapshot()
 
     t_win = time.perf_counter()
@@ -312,12 +327,14 @@ def run_cell(root, cell: Cell, seed: int, seconds: float, trace: bool, *,
     del state
     prep.eng = None
     gc.collect()
-    mark = time.perf_counter()
+    stage.mark, compile_s = time.perf_counter(), clog.backend_s
     ref = reference_readings(prep, seed, pool)
     correct, checks = check.compare(prog_losses, prog_readings, *ref,
                                     limits=cell.limits)
     stage("reference")
-    log_line.update(stages=stages, slowest_compiles=clog.slowest())
+    log_line.update(stages=stage.seconds,
+                    reference_compile_s=clog.backend_s - compile_s,
+                    slowest_compiles=clog.slowest())
     log(log_line)
 
     tr = prep.cell.traffic

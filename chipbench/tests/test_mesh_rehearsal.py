@@ -1,7 +1,8 @@
 """Four-chip cells on the mesh executor, rehearsed on four virtual CPU
 devices through the harness's inner function (the CLI refuses a non-TPU
 device): the tiny traffic sound, with each planted fault and with the
-control (parameters stored in bfloat16), and the hierarchy of the mesh
+control (parameters stored in bfloat16), its readings under the state's
+own sharding beside the replicated ones, and the hierarchy of the mesh
 cell's traffic file at tiny sizes.  XLA_FLAGS must be set before JAX
 starts, hence one subprocess for all of them.  And ``sync.collective_frac``
 read from a hand-made trace summary."""
@@ -22,10 +23,31 @@ MESH_CELL = "mesh4.g50i5.b4x256"
 SCRIPT = """
 import contextlib, json, sys, time, pathlib
 sys.path[:0] = [{src!r}, {repo!r}]
-from chipbench import faults, harness
+import jax, numpy as np
+from chipbench import check, faults, harness
 from chipbench.registry import resolve
 from chipbench.tests.tiny import make_root
-out = {{}}
+out = {{"readings": []}}
+readings = check.program_readings
+
+def beside_replicated(params, p0, leaders):
+    # each reading under the state's sharding, beside the same reading of
+    # the parameters replicated on every device, and the sharding the
+    # compiled readings program takes
+    got = jax.device_get(readings(params, p0, leaders))
+    want = jax.device_get(check._readings(harness._like(params, params), p0,
+                                          tuple(int(x) for x in leaders)))
+    takes = jax.tree.leaves(check.readings_fn(params, leaders).lower(
+        params, p0).compile().input_shardings[0][0])
+    out["readings"].append({{
+        "leaders": [int(x) for x in leaders],
+        "equal": all(np.array_equal(a, b) and a.dtype == b.dtype == np.float32
+                     for a, b in zip(got, want)),
+        "state_sharding": len(takes) == len(jax.tree.leaves(params)) and all(
+            s.is_equivalent_to(x.sharding, x.ndim)
+            for s, x in zip(takes, jax.tree.leaves(params)))}})
+    return got
+
 for traffic, variants in json.loads({runs!r}):
     root = make_root(pathlib.Path({tmp!r}) / str(len(out)),
                      traffic=traffic, chips=4)
@@ -33,6 +55,8 @@ for traffic, variants in json.loads({runs!r}):
     for variant in variants:
         kw = dict(model_overrides={{"param_dtype": "bfloat16"}}) \\
             if variant == "control" else {{}}
+        spy = traffic["name"] == "tiny" and variant == "sound"
+        check.program_readings = beside_replicated if spy else readings
         with faults.FAULTS.get(variant, contextlib.nullcontext)():
             r = harness.run_cell(root, cell, 9, 0.3, False,
                                  t_start=time.perf_counter(),
@@ -79,6 +103,15 @@ def test_mesh_cell_on_four_virtual_devices(runs, traffic):
     assert tr["backend"] == "mesh"
     if traffic == MESH_CELL:   # the global average is among the checked
         assert tr["check_steps"] == tr["periods"][0]
+
+
+def test_readings_under_the_state_sharding_are_the_replicated_ones(runs):
+    # the tiny traffic averages in pairs after step 2 and over all four
+    # workers after step 4: one reading each
+    seen = runs["readings"]
+    assert [r["leaders"] for r in seen] == [[0, 0, 2, 2], [0, 0, 0, 0]]
+    assert all(r["equal"] for r in seen), seen
+    assert all(r["state_sharding"] for r in seen), seen
 
 
 @pytest.mark.parametrize("variant", BROKEN)

@@ -95,3 +95,23 @@ def test_collective_time_counts_inside_the_window():
     s = reduce_events(events)
     assert s.collective_s == {DEV: pytest.approx(10e-9)}
     assert s.collective_s[DEV] <= s.busy_s_per_device[DEV]
+
+
+def test_a_collective_is_told_by_its_hlo_opcode():
+    # a TPU trace names each op by its HLO text; an all-reduce that a psum
+    # made carries the psum's name, and an op that only reads one is none
+    events = [
+        (DEV, OPS, "%psum_invariant.15 = f32[1,18]{1,0:T(8,128)} "
+         "all-reduce(%tanh_fusion), channel_id=1, replica_groups={{0,1}}",
+         0, 10),
+        (DEV, OPS, "%fusion.4 = f32[1,18]{1,0} fusion(%psum_invariant.15, "
+         "%all-reduce.2), kind=kLoop, calls=%fused_computation", 10, 10),
+        (DEV, OPS, "%collective-permute-start.1 = (f32[8]{0}, f32[8]{0}) "
+         "collective-permute-start(%x), source_target_pairs={{0,1}}", 20, 5),
+        ("/host:CPU", "python", "chipbench.run_rounds", 0, 25),
+    ]
+    s = reduce_events(events)
+    assert s.collective_s == {DEV: pytest.approx(15e-9)}
+    assert [n for n, _ in s.top_ops] == [
+        "psum_invariant.15 f32[1,18]", "fusion.4 f32[1,18]",
+        "collective-permute-start.1 f32[8]"]

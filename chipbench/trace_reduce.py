@@ -17,7 +17,9 @@ the device's operations.  The reduction works on flat event records
   which says what the host was doing while the device waited;
 * collective time per device is the summed duration, inside the window, of
   its all-reduce, all-gather, reduce-scatter, all-to-all and
-  collective-permute operations.
+  collective-permute operations, told by the opcode of their HLO text (a
+  TPU trace names an op after the JAX operation it came from:
+  ``%psum_invariant.3 = f32[...] all-reduce(...)``).
 """
 from __future__ import annotations
 
@@ -32,6 +34,8 @@ SPAN_PREFIX = "chipbench."
 OPS_LINE = "XLA Ops"
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
+_COLLECTIVE_OP = re.compile(r"[\s)}](?:%s)(?:-start|-done)?\(" %
+                            "|".join(COLLECTIVES))
 # ops that hold other ops on the same line (a round's scan is one
 # ``while``): they count towards busy time, not towards the top ops
 CONTROL_FLOW = ("while", "conditional", "call")
@@ -80,7 +84,8 @@ def reduce_events(events: Iterable[Event],
     for plane, line, name, start, dur in events:
         if _is_device(plane):
             if line == OPS_LINE:
-                ops[plane].append((op_name(name), start, start + dur))
+                ops[plane].append((op_name(name), start, start + dur,
+                                   is_collective(name)))
         elif name.startswith(SPAN_PREFIX):
             spans.append((name, start, start + dur))
     if not ops:
@@ -93,18 +98,18 @@ def reduce_events(events: Iterable[Event],
     busy, gaps = {}, []
     per_op, coll = defaultdict(float), {}
     for plane, evs in ops.items():
-        merged = _clip(union((s, e) for _, s, e in evs), t0, t1)
+        merged = _clip(union((s, e) for _, s, e, _ in evs), t0, t1)
         busy[plane] = sum(e - s for s, e in merged) * 1e-9
         edges = [t0] + [x for iv in merged for x in iv] + [t1]
         for gs, ge in zip(edges[::2], edges[1::2]):
             if ge > gs:
                 gaps.append((_label(spans, gs, ge), (ge - gs) * 1e-9))
         coll[plane] = 0.0
-        for name, s, e in evs:
+        for name, s, e, collective in evs:
             if e > t0 and s < t1:
                 if not name.startswith(CONTROL_FLOW):
                     per_op[name] += (min(e, t1) - max(s, t0)) * 1e-9 / len(ops)
-                if any(c in name for c in COLLECTIVES):
+                if collective:
                     coll[plane] += (min(e, t1) - max(s, t0)) * 1e-9
     gaps.sort(key=lambda g: -g[1])
     return TraceSummary(
@@ -113,6 +118,15 @@ def reduce_events(events: Iterable[Event],
         busy_s_per_device=busy,
         top_ops=sorted(per_op.items(), key=lambda kv: -kv[1])[:top],
         idle_gaps=gaps[:top], collective_s=coll)
+
+
+def is_collective(name: str) -> bool:
+    """Whether a device op is a collective: by the opcode of its HLO text,
+    or by its name where the trace gives no HLO text."""
+    _, sep, rhs = name.partition(" = ")
+    if sep:
+        return bool(_COLLECTIVE_OP.search(rhs))
+    return any(c in name for c in COLLECTIVES)
 
 
 def op_name(name: str) -> str:
